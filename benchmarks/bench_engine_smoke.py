@@ -54,9 +54,10 @@ def main() -> None:
     from repro.configs import registry
     from repro.configs.base import DFLConfig, ParallelConfig, ShapeConfig
     from repro.launch import steps
+    from repro.launch.mesh import make_mesh
     from repro.models import params as params_lib
 
-    mesh = jax.make_mesh((4, 4), ("data", "model"))
+    mesh = make_mesh((4, 4), ("data", "model"))
     cfg = registry.reduced("qwen2.5-3b")  # single-dtype smoke tree
     shape = ShapeConfig("t", 64, 8, "train")
     dfl = DFLConfig(degree=2, round_plan="one_peer")

@@ -47,8 +47,7 @@ def _from_storable(arr: np.ndarray, dtype_name: str) -> np.ndarray:
 
 
 def _leaf_paths(tree: PyTree) -> tuple[list[str], list[Any]]:
-    # jax.tree_util spelling: jax.tree.leaves_with_path is absent in this jax
-    flat = jax.tree_util.tree_leaves_with_path(tree)
+    flat = jax.tree.leaves_with_path(tree)
     names = [jax.tree_util.keystr(p) for p, _ in flat]
     leaves = [l for _, l in flat]
     return names, leaves

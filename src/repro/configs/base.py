@@ -170,10 +170,11 @@ LM_SHAPES: tuple[ShapeConfig, ...] = (
 class ParallelConfig:
     """How one (arch x mesh) cell factorizes the device grid.
 
-    The production mesh is fixed at (16,16)=(data,model) or (2,16,16)=
-    (pod,data,model); `clients_per_pod` coarsens the DFL client axis by
-    regrouping data rows into (client, fsdp): data=16 -> client=clients_per_pod,
-    fsdp=16/clients_per_pod. fsdp does ZeRO sharding of each client's
+    The production mesh is (data, model) = (visible devices, 1) on a real
+    host, or the dry-run's placeholder pods (16,16)=(data,model) and
+    (2,16,16)=(pod,data,model); `clients_per_pod` coarsens the DFL client
+    axis by regrouping data rows into (client, fsdp): client=clients_per_pod,
+    fsdp=data/clients_per_pod. fsdp does ZeRO sharding of each client's
     params/momentum AND data-parallelism of the client's local batch.
     """
 

@@ -102,6 +102,10 @@ __all__ = [
 
 PyTree = Any
 
+# the stacked/blocked mix einsums run at full f32: a TPU's default f32 dot
+# takes bf16 passes, which would break parity with the f32 oracles
+_EXACT = jax.lax.Precision.HIGHEST
+
 SUBSTRATES = ("shard_map", "stacked", "blocked", "per_leaf", "dense")
 SCREENS = ("none", "norm_clip", "trimmed_mean")
 # the cells the delay and screen layers are wired for; "blocked" joins when
@@ -1354,7 +1358,8 @@ class GossipExecutor:
             # gathered neighbor rows go through the codec / the snapshot
             stack = jnp.stack([buf] + [jnp.take(src, idx, axis=0)
                                        for idx in gathers], axis=1)
-            out = jnp.einsum("nk,nk...->n...", w, stack.astype(jnp.float32))
+            out = jnp.einsum("nk,nk...->n...", w, stack.astype(jnp.float32),
+                             precision=_EXACT)
             out_bufs.append(out.astype(buf.dtype))
             if tel is not None and tel.consensus:
                 for s in range(len(gathers)):
@@ -1437,7 +1442,7 @@ class GossipExecutor:
                 stack = jnp.stack([xj] + [jnp.take(src, g, axis=0)
                                           for g in gathers], axis=1)
                 y = jnp.einsum("nk,nk...->n...", w,
-                               stack.astype(jnp.float32))
+                               stack.astype(jnp.float32), precision=_EXACT)
                 if j == 0 and tel is not None and tel.consensus:
                     for s in range(len(gathers)):
                         resid = resid + tcontrib[:, 1 + s] * sq(
@@ -1524,7 +1529,7 @@ class GossipExecutor:
 
             def mixer(stack):
                 return jnp.einsum("nk,nk...->n...", eff,
-                                  stack.astype(jnp.float32))
+                                  stack.astype(jnp.float32), precision=_EXACT)
         else:  # trimmed_mean
             raw, contrib = gossip.raw_contrib_tables(spec, alive, gates)
             trim_u = jnp.maximum(raw, 0.0) * contrib
@@ -1734,7 +1739,7 @@ class GossipExecutor:
             # gathered neighbor rows go through the codec wire
             stack = jnp.stack([buf] + srcs, axis=1)  # (B, S+1, rows, 128)
             out = jnp.einsum("bk,bk...->b...", w_local,
-                             stack.astype(jnp.float32))
+                             stack.astype(jnp.float32), precision=_EXACT)
             out_bufs.append(out.astype(buf.dtype))
             if tel is not None and tel.consensus:
                 # residuals off the already-gathered stack: the telemetry

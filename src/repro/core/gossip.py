@@ -288,7 +288,8 @@ def mix_dense(tree: PyTree, m: jax.Array | np.ndarray) -> PyTree:
 
     def _mix(x):
         flat = x.reshape(x.shape[0], -1)
-        out = jnp.einsum("cd,df->cf", m.astype(flat.dtype), flat)
+        out = jnp.einsum("cd,df->cf", m.astype(flat.dtype), flat,
+                         precision=jax.lax.Precision.HIGHEST)
         return out.reshape(x.shape)
 
     return jax.tree.map(_mix, tree)
@@ -601,19 +602,13 @@ def mix_packed_stacked_delayed(tree: PyTree,
     return ex(tree, state=snapshot, alive=alive, gates=gates)
 
 
-def _axis_size(name: str) -> jax.Array | int:
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(name)
-    return jax.lax.psum(1, name)  # pre-0.4.38 spelling; folds to a constant
-
-
 def _client_index(axis_names: str | tuple[str, ...]) -> jax.Array:
     """Flattened client index over (possibly) multiple mesh axes, row-major."""
     if isinstance(axis_names, str):
         return jax.lax.axis_index(axis_names)
     idx = jax.lax.axis_index(axis_names[0])
     for name in axis_names[1:]:
-        idx = idx * _axis_size(name) + jax.lax.axis_index(name)
+        idx = idx * jax.lax.axis_size(name) + jax.lax.axis_index(name)
     return idx
 
 

@@ -49,6 +49,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 LANE = 128
 DEFAULT_BLOCK_ROWS = 256  # 256 x 128 x f32 = 128 KiB per buffer tile
@@ -132,25 +133,27 @@ def _mix_trimmed_kernel(x_ref, u_ref, l_ref, o_ref, *, trim):
 
 
 def _mix_trimmed_quant_kernel(f_ref, q_ref, s_ref, u_ref, l_ref, o_ref, *,
-                              trim):
+                              trim, n_s):
     """Dequant-side trimmed mix: the self tile is fresh f32, the K-1
     received tiles are int8 with their (per-buffer or per-row-block) f32
-    scale riding in s_ref (K-1, 1) — dequantized in-register, then the same
-    trim reduction as `_mix_trimmed_kernel`.
+    scales in s_ref, the (K-1, n_s) array as one (1, m) SMEM row (see
+    `repro.kernels.quant_gossip.kernel`) — dequantized
+    in-register, then the same trim reduction as `_mix_trimmed_kernel`.
     """
     fresh = f_ref[...].astype(jnp.float32)
     q = q_ref[...]
-    s = s_ref[...].astype(jnp.float32)
+    col = pl.program_id(0) if n_s > 1 else 0
     u = u_ref[...].astype(jnp.float32)
     lv = l_ref[...].astype(jnp.float32)
-    vals = [fresh] + [q[i].astype(jnp.float32) * s[i, 0]
+    vals = [fresh] + [q[i].astype(jnp.float32) * s_ref[0, i * n_s + col]
                       for i in range(q.shape[0])]
     o_ref[...] = _trimmed_reduce(vals, u, lv, trim,
                                  o_ref.shape).astype(o_ref.dtype)
 
 
 def _sqnorm_kernel(x_ref, o_ref):
-    """Per-lane partial squared norms of one (BR, LANE) tile: o = (1, LANE).
+    """Per-lane partial squared norms of one (BR, LANE) tile: o = (1, LANE),
+    the tile's own (1, LANE) slab of the (n_blocks, 1, LANE) output.
     The host-side wrapper finishes the reduction with one (n_blocks, LANE)
     sum — the payload is read exactly once."""
     x = x_ref[...].astype(jnp.float32)
@@ -233,16 +236,17 @@ def gossip_mix_2d_trimmed_quant(fresh: jax.Array, qstack: jax.Array,
     grid = (n_blocks,)
     fresh_spec = pl.BlockSpec((block_rows, LANE), lambda i: (i, 0))
     q_spec = pl.BlockSpec((km1, block_rows, LANE), lambda i: (0, i, 0))
-    s_spec = (pl.BlockSpec((km1, 1), lambda i: (0, i)) if n_s == n_blocks
-              else pl.BlockSpec((km1, 1), lambda i: (0, 0)))
+    # all scales resident in SMEM; a tile reads column i (per-row-block)
+    s_spec = pl.BlockSpec(memory_space=pltpu.SMEM)
     vec_spec = pl.BlockSpec((k, 1), lambda i: (0, 0))
     out_spec = pl.BlockSpec((block_rows, LANE), lambda i: (i, 0))
     out_shape = jax.ShapeDtypeStruct((rows, LANE), fresh.dtype)
     return pl.pallas_call(
-        functools.partial(_mix_trimmed_quant_kernel, trim=trim), grid=grid,
+        functools.partial(_mix_trimmed_quant_kernel, trim=trim, n_s=n_s),
+        grid=grid,
         in_specs=[fresh_spec, q_spec, s_spec, vec_spec, vec_spec],
         out_specs=out_spec, out_shape=out_shape, interpret=interpret,
-    )(fresh, qstack, scales.astype(jnp.float32), u2, l2)
+    )(fresh, qstack, scales.reshape(1, -1).astype(jnp.float32), u2, l2)
 
 
 @functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
@@ -255,9 +259,11 @@ def sqnorms_2d(buf: jax.Array, *, block_rows: int = DEFAULT_BLOCK_ROWS,
     n_blocks = rows // block_rows
     grid = (n_blocks,)
     in_spec = pl.BlockSpec((block_rows, LANE), lambda i: (i, 0))
-    out_spec = pl.BlockSpec((1, LANE), lambda i: (i, 0))
-    out_shape = jax.ShapeDtypeStruct((n_blocks, LANE), jnp.float32)
+    # a (1, LANE) block is only legal as the whole of the array's last two
+    # dims, so each tile owns one (1, LANE) slab of a 3-D output
+    out_spec = pl.BlockSpec((None, 1, LANE), lambda i: (i, 0, 0))
+    out_shape = jax.ShapeDtypeStruct((n_blocks, 1, LANE), jnp.float32)
     return pl.pallas_call(
         _sqnorm_kernel, grid=grid, in_specs=[in_spec],
         out_specs=out_spec, out_shape=out_shape, interpret=interpret,
-    )(buf)
+    )(buf).reshape(n_blocks, LANE)

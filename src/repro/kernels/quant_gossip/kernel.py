@@ -11,10 +11,18 @@ Two scale granularities share the same kernel bodies:
 * per-buffer (`quantize_2d` / `dequant_accumulate_2d`): one f32 scale for the
   whole buffer — error is governed by the buffer-wide amax;
 * per-row-block (`quantize_2d_blockwise` / `dequant_accumulate_2d_blockwise`):
-  one f32 scale per (block_rows x LANE) kernel tile, selected by the grid
-  index map — a tile of small-magnitude parameters no longer inherits the
-  quantization step of the buffer's global amax. Only the scalar-operand
-  BlockSpecs differ; the payload traffic is identical.
+  one f32 scale per (block_rows x LANE) kernel tile — a tile of
+  small-magnitude parameters no longer inherits the quantization step of
+  the buffer's global amax. The payload traffic is identical.
+
+Every scalar operand (scales, mixing weights, alive weights, and the sparse
+entries of `scatter_accumulate_2d`) is one (1, m) f32/int32 row resident in
+SMEM for the whole grid; a tile reads its own group of scalars at
+``program_id * n``. The TPU compiler refuses per-tile VMEM blocks of one
+scalar (a block's last two dims must be multiples of (8, 128) or the whole
+array), and it cannot store a scalar into VMEM. The leading unit dim keeps
+that rule satisfied when the engine vmaps a kernel over clients: the batch
+dim then lands in front of the whole (1, m) row.
 """
 from __future__ import annotations
 
@@ -23,69 +31,83 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 LANE = 128
 DEFAULT_BLOCK_ROWS = 256
 
+# the whole (1, m) operand in SMEM, read with scalar loads
+_SMEM = pl.BlockSpec(memory_space=pltpu.SMEM)
+
+
+def _smem_row(x, dtype=jnp.float32):
+    return x.reshape(1, -1).astype(dtype)
+
+
+def _tile_scalars(s_ref, n):
+    """This tile's n scalars from a (1, m) SMEM operand that holds either
+    one group of n for the whole buffer or one group per grid tile."""
+    base = pl.program_id(0) * n if s_ref.shape[1] > n else 0
+    return [s_ref[0, base + j] for j in range(n)]
+
 
 def _quant_kernel(x_ref, s_ref, q_ref):
-    inv = 1.0 / s_ref[0, 0]
-    x = x_ref[...].astype(jnp.float32) * inv
+    (scale,) = _tile_scalars(s_ref, 1)
+    x = x_ref[...].astype(jnp.float32) * (1.0 / scale)
     q_ref[...] = jnp.clip(jnp.round(x), -127.0, 127.0).astype(jnp.int8)
 
 
-def _dequant_acc_kernel(q_ref, s_ref, acc_ref, o_ref):
+def _dequant_acc_kernel(q_ref, s_ref, acc_ref, o_ref, *, n_scalars):
     """o = acc + alive * c * (q * s).
 
-    s_ref = (1, 2) holding (scale, c), or (1, 3) holding (scale, c, alive) —
-    the failure-aware gossip path folds the sender's (renormalized) alive
+    Per tile, s_ref holds (scale, c) or (scale, c, alive) — the
+    failure-aware gossip path folds the sender's (renormalized) alive
     weight into the same fused pass instead of adding a masking pass.
     """
-    scale = s_ref[0, 0]
-    c = s_ref[0, 1]
-    if s_ref.shape[1] == 3:
-        c = c * s_ref[0, 2]
+    scale, c, *alive = _tile_scalars(s_ref, n_scalars)
+    if alive:
+        c = c * alive[0]
     o_ref[...] = (acc_ref[...].astype(jnp.float32)
                   + c * scale * q_ref[...].astype(jnp.float32)
                   ).astype(o_ref.dtype)
 
 
-def _scatter_acc_kernel(v_ref, i_ref, s_ref, acc_ref, o_ref):
+def _scatter_acc_kernel(v_ref, i_ref, s_ref, acc_ref, o_ref, *, n_scalars):
     """o = acc + alive * c * scatter(vals at flat idx).
 
-    ``v_ref`` / ``i_ref`` hold the lane-folded sparse entries — (k_rows,
-    LANE) f32 values and int32 flat indices into THIS (dense) buffer, zero-
-    padded past k (val 0 at idx 0 is a no-op). ``s_ref`` = (1, 1) holding
-    (c,) or (1, 2) holding (c, alive) — the failure-aware gossip path folds
-    the sender's renormalized alive weight into the same fused pass, exactly
-    like ``_dequant_acc_kernel``. Grid tiles cover the dense accumulator;
-    every tile walks all k entries and lands the ones inside its flat range
-    (top-k keeps k small — the walk is k scalar ops per tile, while the
-    dense copy stays one vector pass).
+    ``v_ref`` / ``i_ref`` hold the sparse entries in SMEM — (k,) f32 values
+    and int32 flat indices into THIS (dense) buffer, zero-padded past k
+    (val 0 at idx 0 is a no-op). ``s_ref`` holds (c,) or (c, alive) — the
+    failure-aware gossip path folds the sender's renormalized alive weight
+    into the same fused pass, exactly like ``_dequant_acc_kernel``. Grid
+    tiles cover the dense accumulator; every tile walks all k entries and
+    lands the ones inside its flat range as a masked update of one lane
+    row (top-k keeps k small — the walk is k scalar steps per tile, while
+    the dense copy stays one vector pass).
     """
-    c = s_ref[0, 0]
-    if s_ref.shape[1] == 2:
-        c = c * s_ref[0, 1]
+    c, *alive = _tile_scalars(s_ref, n_scalars)
+    if alive:
+        c = c * alive[0]
     block_rows, lane = o_ref.shape
     tile = block_rows * lane
     base = pl.program_id(0) * tile
     o_ref[...] = acc_ref[...]
-    kr, kl = i_ref.shape
+    lanes = jax.lax.broadcasted_iota(jnp.int32, (1, lane), 1)
 
     def body(e, carry):
-        j = i_ref[e // kl, e % kl] - base
+        j = i_ref[0, e] - base
 
         @pl.when((j >= 0) & (j < tile))
         def _():
             r = j // lane
-            col = j - r * lane
-            o_ref[r, col] = (o_ref[r, col].astype(jnp.float32)
-                             + c * v_ref[e // kl, e % kl]
-                             ).astype(o_ref.dtype)
+            row = o_ref[pl.ds(r, 1), :]
+            upd = (row.astype(jnp.float32) + c * v_ref[0, e]
+                   ).astype(row.dtype)
+            o_ref[pl.ds(r, 1), :] = jnp.where(lanes == j - r * lane, upd, row)
 
         return carry
 
-    jax.lax.fori_loop(0, kr * kl, body, 0)
+    jax.lax.fori_loop(0, v_ref.shape[1], body, 0)
 
 
 @functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
@@ -95,29 +117,23 @@ def scatter_accumulate_2d(vals: jax.Array, idx: jax.Array,
                           interpret: bool = False) -> jax.Array:
     """Fused sparse scatter-accumulate over a packed (rows, LANE) buffer.
 
-    ``vals`` / ``idx`` are (k_rows, LANE) lane-folded sparse entries (f32 /
-    int32, zero-padded); ``c_alive`` is (1, 1) = (c,) or (1, 2) =
-    (c, alive weight). The whole sparse set rides into every grid tile
-    (index map (0, 0)) — it is ~k_fraction of one tile, so the duplicated
-    VMEM traffic is noise next to the dense acc pass."""
+    ``vals`` / ``idx`` are the (k,) sparse entries (f32 / int32, zero-
+    padded); ``c_alive`` is (1,) = (c,) or (2,) = (c, alive weight). The
+    whole sparse set sits in SMEM for every grid tile."""
     rows, lane = acc.shape
     assert lane == LANE and rows % block_rows == 0
-    kr, kl = vals.shape
-    assert kl == LANE and idx.shape == vals.shape, (vals.shape, idx.shape)
+    assert vals.ndim == 1 and idx.shape == vals.shape, (vals.shape, idx.shape)
     n_scalars = int(c_alive.size)
     assert n_scalars in (1, 2), c_alive.shape
     blk = pl.BlockSpec((block_rows, LANE), lambda i: (i, 0))
-    full = pl.BlockSpec((kr, LANE), lambda i: (0, 0))
     return pl.pallas_call(
-        _scatter_acc_kernel,
+        functools.partial(_scatter_acc_kernel, n_scalars=n_scalars),
         grid=(rows // block_rows,),
-        in_specs=[full, full,
-                  pl.BlockSpec((1, n_scalars), lambda i: (0, 0)), blk],
+        in_specs=[_SMEM, _SMEM, _SMEM, blk],
         out_specs=blk,
         out_shape=jax.ShapeDtypeStruct((rows, LANE), acc.dtype),
         interpret=interpret,
-    )(vals, idx.astype(jnp.int32),
-      c_alive.reshape(1, n_scalars).astype(jnp.float32), acc)
+    )(_smem_row(vals), _smem_row(idx, jnp.int32), _smem_row(c_alive), acc)
 
 
 @functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
@@ -130,11 +146,11 @@ def quantize_2d(x: jax.Array, scale: jax.Array, *,
     return pl.pallas_call(
         _quant_kernel,
         grid=(rows // block_rows,),
-        in_specs=[blk, pl.BlockSpec((1, 1), lambda i: (0, 0))],
+        in_specs=[blk, _SMEM],
         out_specs=blk,
         out_shape=jax.ShapeDtypeStruct((rows, LANE), jnp.int8),
         interpret=interpret,
-    )(x, scale.reshape(1, 1).astype(jnp.float32))
+    )(x, _smem_row(scale))
 
 
 @functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
@@ -148,13 +164,13 @@ def dequant_accumulate_2d(q: jax.Array, scale_c: jax.Array, acc: jax.Array, *,
     assert n_scalars in (2, 3), scale_c.shape
     blk = pl.BlockSpec((block_rows, LANE), lambda i: (i, 0))
     return pl.pallas_call(
-        _dequant_acc_kernel,
+        functools.partial(_dequant_acc_kernel, n_scalars=n_scalars),
         grid=(rows // block_rows,),
-        in_specs=[blk, pl.BlockSpec((1, n_scalars), lambda i: (0, 0)), blk],
+        in_specs=[blk, _SMEM, blk],
         out_specs=blk,
         out_shape=jax.ShapeDtypeStruct((rows, LANE), acc.dtype),
         interpret=interpret,
-    )(q, scale_c.reshape(1, n_scalars).astype(jnp.float32), acc)
+    )(q, _smem_row(scale_c), acc)
 
 
 @functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
@@ -162,7 +178,7 @@ def quantize_2d_blockwise(x: jax.Array, scales: jax.Array, *,
                           block_rows: int = DEFAULT_BLOCK_ROWS,
                           interpret: bool = False) -> jax.Array:
     """Per-row-block quantize: ``scales`` is (n_blocks,), one f32 scale per
-    (block_rows, LANE) tile; tile i reads scales[i] via the grid index map."""
+    (block_rows, LANE) tile; tile i reads scales[i]."""
     rows, lane = x.shape
     assert lane == LANE and rows % block_rows == 0
     n_blocks = rows // block_rows
@@ -171,11 +187,11 @@ def quantize_2d_blockwise(x: jax.Array, scales: jax.Array, *,
     return pl.pallas_call(
         _quant_kernel,
         grid=(n_blocks,),
-        in_specs=[blk, pl.BlockSpec((1, 1), lambda i: (i, 0))],
+        in_specs=[blk, _SMEM],
         out_specs=blk,
         out_shape=jax.ShapeDtypeStruct((rows, LANE), jnp.int8),
         interpret=interpret,
-    )(x, scales.reshape(n_blocks, 1).astype(jnp.float32))
+    )(x, _smem_row(scales))
 
 
 @functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
@@ -194,10 +210,10 @@ def dequant_accumulate_2d_blockwise(q: jax.Array, scale_c: jax.Array,
         scale_c.shape
     blk = pl.BlockSpec((block_rows, LANE), lambda i: (i, 0))
     return pl.pallas_call(
-        _dequant_acc_kernel,
+        functools.partial(_dequant_acc_kernel, n_scalars=n_scalars),
         grid=(n_blocks,),
-        in_specs=[blk, pl.BlockSpec((1, n_scalars), lambda i: (i, 0)), blk],
+        in_specs=[blk, _SMEM, blk],
         out_specs=blk,
         out_shape=jax.ShapeDtypeStruct((rows, LANE), acc.dtype),
         interpret=interpret,
-    )(q, scale_c.astype(jnp.float32), acc)
+    )(q, _smem_row(scale_c), acc)

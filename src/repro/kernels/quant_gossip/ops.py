@@ -183,22 +183,11 @@ def scatter_accumulate_packed(vals: jax.Array, idx: jax.Array, c,
         if alive is not None:
             eff_c = eff_c * jnp.asarray(alive, jnp.float32)
         return _ref.scatter_accumulate(vals, idx, eff_c, acc)
-    k = vals.shape[0]
-    pad = (-k) % _k.LANE
-
-    def fold(x, fill):
-        xf = x.reshape(-1)
-        if pad:
-            xf = jnp.pad(xf, (0, pad), constant_values=fill)
-        return xf.reshape(-1, _k.LANE)
-
     scalars = [jnp.asarray(c, jnp.float32)]
     if alive is not None:
         scalars.append(jnp.asarray(alive, jnp.float32))
-    sc = jnp.stack(scalars).reshape(1, len(scalars))
     return _k.scatter_accumulate_2d(
-        fold(vals.astype(jnp.float32), 0.0), fold(idx.astype(jnp.int32), 0),
-        sc, acc, block_rows=block_rows,
+        vals, idx, jnp.stack(scalars), acc, block_rows=block_rows,
         interpret=(impl == "pallas_interpret"))
 
 

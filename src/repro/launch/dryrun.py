@@ -40,7 +40,7 @@ from repro.roofline import analysis, hw
 
 
 def _mesh(kind: str):
-    return mesh_lib.make_production_mesh(multi_pod=(kind == "multi"))
+    return mesh_lib.make_production_mesh(pod=kind)
 
 
 def run_cell(arch: str, shape_name: str, mesh_kind: str,

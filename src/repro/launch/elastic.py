@@ -593,6 +593,12 @@ class ElasticTrainer:
         rnd = self.round_no
         self.round_no += 1
         lr = jnp.asarray(lr, jnp.float32)
+        if self.gossip_block:
+            # the blocked round returns params committed to the client
+            # mesh; committing round 0's input there too keeps every round
+            # on one trace (the sharding is part of the traced type)
+            params = jax.device_put(
+                params, NamedSharding(self._gossip_mesh, P("clients")))
         if self.step_builder is not None:
             # custom builders keep the documented 5-arg StepBuilder contract
             # (screens/attacks with a builder are rejected in __post_init__)

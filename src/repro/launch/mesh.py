@@ -1,42 +1,58 @@
 """Production meshes and the DFL device-grid factorization.
 
-`make_production_mesh` is the prescribed entry point:
-    single-pod: (16, 16)       axes ("data", "model")     = 256 chips
-    multi-pod:  (2, 16, 16)    axes ("pod", "data", "model") = 512 chips
+`make_production_mesh` builds the ("data", "model") mesh from the devices
+the process can see: one chip is a (1, 1) mesh, a v5e 2x2 host a (4, 1)
+mesh (every chip on "data", so each can hold its own DFL client). The pod
+shapes (16, 16) and (2, 16, 16) remain as `POD_SHAPES`, the placeholder
+worlds that `repro.launch.dryrun` compiles for.
 
 `derive_dfl_mesh` refactors the same device grid for the DFL train step:
 the "data" axis splits into (client, fsdp) — `clients_per_pod` DFL clients
-per pod, each internally ZeRO/data-parallel over fsdp = 16/clients_per_pod
-rows — while "model" stays the TP/EP axis. This is a pure reshape of the
+per pod, each internally ZeRO/data-parallel over fsdp = data/clients_per_pod
+rows — while "model" splits into (dp, tp). This is a pure reshape of the
 device array (no re-placement); serving uses the production mesh directly.
+
+Every mesh here has Auto axis types: the train and serve steps place parameters
+with NamedShardings and let GSPMD propagate the rest, which is the
+contract Auto names (jax's Explicit default would put shardings into the
+types and reject plain indexing of sharded values).
 """
 from __future__ import annotations
 
 import jax
 import numpy as np
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
+
+# the pod worlds the dry-run describes with placeholder CPU devices
+POD_SHAPES = {"single": ((16, 16), ("data", "model")),
+              "multi": ((2, 16, 16), ("pod", "data", "model"))}
 
 
 def shard_map(f, mesh: Mesh, *, in_specs, out_specs):
-    """Version-compat *full-manual* shard_map.
+    """*Full-manual* shard_map over every mesh axis.
 
-    Newer jax exposes ``jax.shard_map``; this jax build only has the
-    experimental API (and its SPMD partitioner hard-crashes on partial-auto
-    manual regions — ``IsManualSubgroup`` check — so every shard_map in this
-    repo is fully manual over all mesh axes, with real per-leaf specs).
+    Replication checking is off (``check_vma=False``): the gossip islands
+    return per-device values under specs that do not name every mesh axis
+    (a leaf replicated over tp is mixed identically on each tp shard), which
+    the checker cannot prove.
     """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
-def make_production_mesh(*, multi_pod: bool = False):
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+def make_mesh(shape, axes) -> Mesh:
+    """``jax.make_mesh`` with Auto axis types (see the module docstring)."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes))
+
+
+def make_production_mesh(*, pod: str | None = None) -> Mesh:
+    """("data", "model") mesh over the visible devices; ``pod`` = "single"
+    | "multi" builds the placeholder pod world of `POD_SHAPES` instead
+    (the dry-run's 256 / 512 fake devices)."""
+    if pod is not None:
+        return make_mesh(*POD_SHAPES[pod])
+    return make_mesh((len(jax.devices()), 1), ("data", "model"))
 
 
 def derive_dfl_mesh(mesh: Mesh, clients_per_pod: int, tp: int | None = None) -> Mesh:

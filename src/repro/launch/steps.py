@@ -247,7 +247,18 @@ def build_train_step(cfg: ModelConfig, shape: ShapeConfig, base_mesh: Mesh,
     update_fn = None
     if par.use_fused_sgdm:
         from repro.kernels.fused_sgdm.ops import sgdm_update
-        update_fn = sgdm_update
+
+        # GSPMD cannot partition a Mosaic kernel, so the fused update runs
+        # per shard under a manual shard_map (it is elementwise: each
+        # device updates its own shard). Inside the vmapped client round
+        # a leaf's spec is its client-stacked spec minus the client dim.
+        client_specs = jax.tree.map(lambda s: P(*tuple(s)[1:]), pspecs)
+
+        def update_fn(p, v, g, lr, beta):
+            return mesh_lib.shard_map(
+                lambda p, v, g, lr: sgdm_update(p, v, g, lr, beta), dmesh,
+                in_specs=(client_specs,) * 3 + (P(),),
+                out_specs=(client_specs,) * 2)(p, v, g, lr)
 
     def loss_fn(p, b):
         return api.loss_fn(p, b, remat=remat)

@@ -28,6 +28,7 @@ from repro.configs.base import DFLConfig
 from repro.core import dfedavg, engine as engine_lib, failures as failures_lib, \
     gossip as gossip_lib
 from repro.core.topology import Overlay
+from repro.launch import compile_cache
 from repro.launch.steps import build_overlay
 from repro.models import lstm as lstm_model
 from repro.models import params as params_lib
@@ -287,6 +288,11 @@ class SimTrainer:
             return params, losses, metrics
         return round_fn
 
+    @property
+    def round_fn(self):
+        """The jitted round (local phase + gossip) that ``run`` calls."""
+        return self._round_fn
+
     def _attack_operands(self, rnd: int):
         if self.attack_plan is None:
             return None, None
@@ -351,6 +357,13 @@ class SimTrainer:
             failure_plan: failures_lib.FailurePlan | None = None
             ) -> tuple[PyTree, list[dict]]:
         history: list[dict] = []
+        if self.gossip_block:
+            # the blocked round returns params committed to the client
+            # mesh; committing round 0's input there too keeps every round
+            # on one trace (the sharding is part of the traced type)
+            from jax.sharding import NamedSharding, PartitionSpec as P
+            params = jax.device_put(
+                params, NamedSharding(self._gossip_mesh, P("clients")))
         for rnd in range(start_round, rounds):
             if failure_plan is not None:
                 mask = failure_plan.alive_mask(rnd)
@@ -415,15 +428,39 @@ class SimTrainer:
 
 
 # --------------------------------------------------------------- char-LM app
-def run_char_lm(n_clients=16, rounds=30, topology="expander", degree=4,
-                local_steps=3, batch=8, seq=64, lr=0.5, momentum=0.9,
-                ckpt_dir=None, seed=0, drop_fraction=0.0, drop_round=10,
-                round_plan="static", gossip_delay=0, gossip_sub_rounds=1,
-                gossip_codec="f32", gossip_screen="none",
-                attackers=0, attack_mode="sign_flip",
-                attack_magnitude=1.0, active_set="full", active_k=1,
-                active_shards=2, gossip_block=0,
-                telemetry=False, telemetry_log=None) -> list[dict]:
+@dataclasses.dataclass
+class CharLM:
+    """A char-LM DFL run over the bundled Shakespeare corpus, assembled and
+    ready for ``trainer.run`` (see :func:`build_char_lm`)."""
+
+    trainer: SimTrainer
+    params: PyTree
+    batch_fn: Callable[[int], PyTree]
+    eval_fn: Callable[[PyTree], dict]
+    lr: float
+    failure_plan: failures_lib.FailurePlan | None = None
+    logger: TelemetryLogger | None = None
+    start_round: int = 0
+
+    def run(self, rounds: int) -> tuple[PyTree, list[dict]]:
+        params, history = self.trainer.run(
+            self.params, self.batch_fn, rounds, lr_fn=lambda r: self.lr,
+            eval_fn=self.eval_fn, failure_plan=self.failure_plan,
+            start_round=self.start_round)
+        if self.logger is not None:
+            self.logger.close()
+        return params, history
+
+
+def build_char_lm(n_clients=16, topology="expander", degree=4,
+                  local_steps=3, batch=8, seq=64, lr=0.5, momentum=0.9,
+                  ckpt_dir=None, seed=0, drop_fraction=0.0, drop_round=10,
+                  round_plan="static", gossip_delay=0, gossip_sub_rounds=1,
+                  gossip_codec="f32", gossip_screen="none",
+                  attackers=0, attack_mode="sign_flip",
+                  attack_magnitude=1.0, active_set="full", active_k=1,
+                  active_shards=2, gossip_block=0,
+                  telemetry=False, telemetry_log=None) -> CharLM:
     from repro.data import federated, pipeline, shakespeare
 
     toks, vocab = shakespeare.corpus()
@@ -433,10 +470,8 @@ def run_char_lm(n_clients=16, rounds=30, topology="expander", degree=4,
                                     seed=seed)
     struct = lstm_model.param_struct(vocab=len(vocab))
     rng = jax.random.key(seed)
-    one = params_lib.init_params(struct, rng)
     params = jax.vmap(lambda i: params_lib.init_params(struct, rng))(
         jnp.arange(n_clients))
-    del one
 
     dfl = DFLConfig(topology=topology, degree=degree, seed=seed,
                     round_plan=round_plan)
@@ -489,10 +524,10 @@ def run_char_lm(n_clients=16, rounds=30, topology="expander", degree=4,
                                             "labels": jnp.asarray(b["labels"][0, 0])})
         return {"test_loss": float(loss), "test_acc": float(aux["acc"])}
 
-    plan = None
+    failure_plan = None
     if drop_fraction > 0:
-        plan = failures_lib.sample_failures(n_clients, drop_fraction,
-                                            drop_round, seed=seed)
+        failure_plan = failures_lib.sample_failures(n_clients, drop_fraction,
+                                                    drop_round, seed=seed)
 
     def batch_fn(rnd):
         b = batcher.round_batches(rnd)
@@ -507,12 +542,15 @@ def run_char_lm(n_clients=16, rounds=30, topology="expander", degree=4,
             start = int(meta.get("round", 0)) + 1
             print(f"[resume] from round {start}")
 
-    params, history = trainer.run(params, batch_fn, rounds,
-                                  lr_fn=lambda r: lr, eval_fn=eval_fn,
-                                  failure_plan=plan, start_round=start)
-    if logger is not None:
-        logger.close()
-    return history
+    return CharLM(trainer=trainer, params=params, batch_fn=batch_fn,
+                  eval_fn=eval_fn, lr=lr, failure_plan=failure_plan,
+                  logger=logger, start_round=start)
+
+
+def run_char_lm(rounds=30, **kw) -> list[dict]:
+    """Train the char-LM for ``rounds`` rounds (``kw``: see
+    :func:`build_char_lm`); returns the per-round history records."""
+    return build_char_lm(**kw).run(rounds)[1]
 
 
 def main() -> None:
@@ -570,6 +608,7 @@ def main() -> None:
     ap.add_argument("--drop-fraction", type=float, default=0.0)
     ap.add_argument("--out", default=None)
     args = ap.parse_args()
+    compile_cache.enable()
 
     hist = run_char_lm(n_clients=args.clients, rounds=args.rounds,
                        topology=args.topology, degree=args.degree,

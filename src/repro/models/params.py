@@ -13,6 +13,7 @@ A model declares its parameters as a pytree of :class:`Leaf` descriptors
 from __future__ import annotations
 
 import dataclasses
+import zlib
 from typing import Any
 
 import jax
@@ -46,19 +47,24 @@ def _is_leaf(x) -> bool:
 def _fan_in_scale(leaf: Leaf) -> float:
     if leaf.scale is not None:
         return leaf.scale
-    fan_in = leaf.shape[0] if len(leaf.shape) >= 2 else max(leaf.shape[-1], 1)
+    # a leading "layers" axis stacks per-layer weights (scan over layers);
+    # it is not an input dim
+    shape = leaf.shape[1:] if leaf.axes[:1] == ("layers",) else leaf.shape
+    fan_in = shape[0] if len(shape) >= 2 else max(shape[-1], 1)
     # for 3D projections (embed, heads, hd) fan-in is the first dim
     return 1.0 / np.sqrt(max(fan_in, 1))
 
 
 def init_params(struct: PyTree, rng: jax.Array) -> PyTree:
-    """Materialize arrays; rng folded per-leaf by path hash (deterministic)."""
-    # jax.tree_util spelling: jax.tree.leaves_with_path is absent in this jax
-    paths = jax.tree_util.tree_leaves_with_path(struct, is_leaf=_is_leaf)
+    """Materialize arrays; rng folded per leaf by a CRC-32 of its path, so
+    the same ``rng`` gives the same weights in every process (``hash()`` of
+    a string is salted per process)."""
+    paths = jax.tree.leaves_with_path(struct, is_leaf=_is_leaf)
 
     leaves = []
     for path, leaf in paths:
-        key = jax.random.fold_in(rng, hash(jax.tree_util.keystr(path)) % (2**31))
+        digest = zlib.crc32(jax.tree_util.keystr(path).encode()) % (2**31)
+        key = jax.random.fold_in(rng, digest)
         dt = jnp.dtype(leaf.dtype)
         if leaf.init == "zeros":
             arr = jnp.zeros(leaf.shape, dt)

@@ -135,16 +135,7 @@ class Roofline:
         return dataclasses.asdict(self)
 
 
-def cost_dict(cost) -> dict:
-    """Normalize `compiled.cost_analysis()` across jax versions: some return
-    the properties dict directly, others a one-element list of it."""
-    if isinstance(cost, (list, tuple)):
-        return dict(cost[0]) if cost else {}
-    return cost
-
-
 def cost_bytes(cost: dict) -> float:
-    cost = cost_dict(cost)
     if "bytes accessed" in cost:
         return float(cost["bytes accessed"])
     return float(sum(v for k, v in cost.items() if k.startswith("bytes accessed")))
@@ -155,8 +146,6 @@ def roofline(cost: dict, hlo_text: str, world: int) -> Roofline:
     (`hlo_cost`) because `cost_analysis()` counts while bodies once; the raw
     cost_analysis numbers are kept as a cross-check."""
     from repro.roofline import hlo_cost
-
-    cost = cost_dict(cost)
 
     hc = hlo_cost.analyze_hlo(hlo_text, world)
     flops = hc.flops

@@ -19,9 +19,10 @@ _HARNESS = textwrap.dedent("""
     from repro.launch import steps
     from repro.models import params as P
     from repro.roofline import analysis
+    from repro.launch.mesh import make_mesh
 
     arch, kind, gossip = sys.argv[1], sys.argv[2], sys.argv[3]
-    mesh = jax.make_mesh((4, 4), ("data", "model"))
+    mesh = make_mesh((4, 4), ("data", "model"))
     cfg = registry.reduced(arch)
     if kind == "train":
         shape = ShapeConfig("t", 64, 8, "train")
@@ -81,3 +82,41 @@ def test_gossip_impl_changes_collectives():
 def test_serve_steps_compile_small_mesh(kind):
     res = _run("gemma2-2b", kind)
     assert res["flops"] > 0
+
+
+def test_production_mesh_is_the_visible_devices():
+    """A 2x2 host (4 devices) gets a (4, 1) production mesh with Auto axes,
+    the DFL mesh puts one client on each device, and the shard_map train
+    step built on it ships d collective-permutes on a ring."""
+    code = textwrap.dedent("""
+        import os
+        os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+        import sys; sys.path.insert(0, "src")
+        import jax
+        from jax.sharding import AxisType
+        from repro.configs import registry
+        from repro.configs.base import DFLConfig, ParallelConfig, ShapeConfig
+        from repro.launch import mesh as mesh_lib, steps
+        from repro.models import params as P
+
+        mesh = mesh_lib.make_production_mesh()
+        assert dict(mesh.shape) == {"data": 4, "model": 1}, mesh.shape
+        assert set(mesh.axis_types) == {AxisType.Auto}, mesh.axis_types
+        dmesh = mesh_lib.derive_dfl_mesh(mesh, clients_per_pod=4, tp=1)
+        assert dict(dmesh.shape) == {"client": 4, "fsdp": 1, "dp": 1,
+                                     "tp": 1}, dmesh.shape
+        setup = steps.build_train_step(
+            registry.reduced("internvl2-1b"), ShapeConfig("t", 32, 8, "train"),
+            mesh, ParallelConfig(clients_per_pod=4, tp=1, grad_accum=1),
+            DFLConfig(topology="ring"))
+        assert setup.n_clients == 4
+        text = setup.step_fn.lower(
+            P.shape_structs(setup.param_struct), setup.input_specs["batch"],
+            setup.input_specs["lr"], setup.input_specs["alive"],
+            setup.input_specs["gates"]).as_text()
+        assert text.count("collective_permute") == setup.gossip_spec.degree
+        print("PRODUCTION_MESH_OK")
+    """)
+    out = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                         text=True, cwd=".")
+    assert "PRODUCTION_MESH_OK" in out.stdout, out.stdout + out.stderr

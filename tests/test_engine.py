@@ -379,9 +379,9 @@ class TestShardMapPipelinedQuant:
             import numpy as np, jax, jax.numpy as jnp
             from jax.sharding import NamedSharding, PartitionSpec as P
             from repro.core import engine, gossip, packing, topology
-            from repro.launch.mesh import shard_map
+            from repro.launch.mesh import make_mesh, shard_map
 
-            mesh = jax.make_mesh((8,), ("client",))
+            mesh = make_mesh((8,), ("client",))
             ov = topology.expander_overlay(8, 4, seed=0)
             spec = gossip.make_gossip_spec(ov)
             r = np.random.default_rng(0)
@@ -462,7 +462,8 @@ class TestProductionPipelinedQuantStep:
             from repro.launch import steps
             from repro.models import params as P
 
-            mesh = jax.make_mesh((4, 4), ("data", "model"))
+            from repro.launch.mesh import make_mesh
+            mesh = make_mesh((4, 4), ("data", "model"))
             cfg = registry.reduced("qwen2.5-3b")
             shape = ShapeConfig("t", 64, 8, "train")
             texts = {}
@@ -722,9 +723,9 @@ class TestShardMapScreens:
             import numpy as np, jax, jax.numpy as jnp
             from jax.sharding import NamedSharding, PartitionSpec as P
             from repro.core import engine, gossip, packing, topology
-            from repro.launch.mesh import shard_map
+            from repro.launch.mesh import make_mesh, shard_map
 
-            mesh = jax.make_mesh((8,), ("client",))
+            mesh = make_mesh((8,), ("client",))
             ov = topology.expander_overlay(8, 4, seed=0)
             spec = gossip.make_gossip_spec(ov)
             r = np.random.default_rng(9)
@@ -785,7 +786,8 @@ class TestShardMapScreens:
             from repro.launch import steps
             from repro.models import params as P
 
-            mesh = jax.make_mesh((4, 4), ("data", "model"))
+            from repro.launch.mesh import make_mesh
+            mesh = make_mesh((4, 4), ("data", "model"))
             cfg = registry.reduced("qwen2.5-3b")
             shape = ShapeConfig("t", 64, 8, "train")
             for gi, screen in (("ppermute_packed", "norm_clip"),
@@ -998,9 +1000,9 @@ class TestChebyshevSlowLane:
             import numpy as np, jax, jax.numpy as jnp
             from jax.sharding import NamedSharding, PartitionSpec as P
             from repro.core import engine, gossip, mixing, packing, topology
-            from repro.launch.mesh import shard_map
+            from repro.launch.mesh import make_mesh, shard_map
 
-            mesh = jax.make_mesh((8,), ("client",))
+            mesh = make_mesh((8,), ("client",))
             ov = topology.expander_overlay(8, 4, seed=0)
             spec = gossip.make_gossip_spec(ov)
             r = np.random.default_rng(9)
@@ -1061,7 +1063,8 @@ class TestChebyshevSlowLane:
             from repro.launch import steps
             from repro.models import params as P
 
-            mesh = jax.make_mesh((4, 4), ("data", "model"))
+            from repro.launch.mesh import make_mesh
+            mesh = make_mesh((4, 4), ("data", "model"))
             cfg = registry.reduced("qwen2.5-3b")
             shape = ShapeConfig("t", 64, 8, "train")
             texts, wires = {}, {}

@@ -87,9 +87,9 @@ class TestShardMapGossip:
             import numpy as np, jax, jax.numpy as jnp
             from jax.sharding import NamedSharding, PartitionSpec as P
             from repro.core import gossip, topology
-            from repro.launch.mesh import shard_map
+            from repro.launch.mesh import make_mesh, shard_map
 
-            mesh = jax.make_mesh((8,), ("client",))
+            mesh = make_mesh((8,), ("client",))
             ov = topology.expander_overlay(8, 4, seed=0)
             spec = gossip.make_gossip_spec(ov)
             r = np.random.default_rng(0)

@@ -181,3 +181,28 @@ def test_ssd_chunked_equals_stepwise():
                                rtol=1e-4, atol=1e-4)
     np.testing.assert_allclose(np.asarray(st_c), np.asarray(st2),
                                rtol=1e-4, atol=1e-4)
+
+
+def test_init_params_same_in_every_process():
+    """``--seed`` fixes the weights: two processes with different string-
+    hash salts (PYTHONHASHSEED) init the same struct to identical leaves."""
+    import os
+    import subprocess
+    import sys
+
+    code = ("import hashlib, jax, numpy as np\n"
+            "from repro.models import lstm, params\n"
+            "p = params.init_params(lstm.param_struct(vocab=53),"
+            " jax.random.key(0))\n"
+            "h = hashlib.sha256()\n"
+            "for leaf in jax.tree.leaves(p): h.update(np.asarray(leaf)"
+            ".tobytes())\n"
+            "print(h.hexdigest())\n")
+    digests = []
+    for salt in ("1", "2"):
+        env = dict(os.environ, PYTHONHASHSEED=salt, JAX_PLATFORMS="cpu",
+                   PYTHONPATH=os.pathsep.join(["src"] + sys.path))
+        out = subprocess.run([sys.executable, "-c", code], env=env,
+                             capture_output=True, text=True, check=True)
+        digests.append(out.stdout.strip().splitlines()[-1])
+    assert digests[0] == digests[1], digests

@@ -325,16 +325,19 @@ class TestGatedPackedShardMap:
         the dense gated oracle in f32 (gates+alive composed)."""
         self._run("""
             import os
-            os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+            # bitwise needs both sides rounded alike: on a host with FMA,
+            # XLA:CPU fuses a multiply-add on one side only, so cap the ISA
+            os.environ["XLA_FLAGS"] = ("--xla_force_host_platform_device_count=8"
+                                       " --xla_cpu_max_isa=AVX")
             import sys; sys.path.insert(0, "src")
             import numpy as np, jax, jax.numpy as jnp
             from jax.sharding import NamedSharding, PartitionSpec as P
             from repro.core import gossip, topology
-            from repro.launch.mesh import shard_map
+            from repro.launch.mesh import make_mesh, shard_map
             from repro.overlay.plan import OnePeerPlan
             from repro.telemetry import TraceCounter
 
-            mesh = jax.make_mesh((8,), ("client",))
+            mesh = make_mesh((8,), ("client",))
             ov = topology.expander_overlay(8, 4, seed=0)
             spec = gossip.make_gossip_spec(ov)
             r = np.random.default_rng(0)
@@ -379,7 +382,7 @@ class TestGatedPackedShardMap:
             import numpy as np, jax, jax.numpy as jnp
             from jax.sharding import NamedSharding, PartitionSpec as P
             from repro.core import gossip, spectral, topology
-            from repro.launch.mesh import shard_map
+            from repro.launch.mesh import make_mesh, shard_map
             from repro.overlay import convert
 
             rng = np.random.default_rng(7)
@@ -393,7 +396,7 @@ class TestGatedPackedShardMap:
             np.testing.assert_array_equal(ov.multigraph_adjacency(), adj)
             spec = gossip.make_gossip_spec(ov)
 
-            mesh = jax.make_mesh((8,), ("client",))
+            mesh = make_mesh((8,), ("client",))
             x = {"w": jnp.asarray(rng.standard_normal((8, 6, 5)),
                                   jnp.float32)}
             specs = jax.tree.map(lambda _: P("client"), x)

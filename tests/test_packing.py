@@ -135,9 +135,9 @@ class TestPackedGossipParity:
             import numpy as np, jax, jax.numpy as jnp
             from jax.sharding import NamedSharding, PartitionSpec as P
             from repro.core import gossip, topology
-            from repro.launch.mesh import shard_map
+            from repro.launch.mesh import make_mesh, shard_map
 
-            mesh = jax.make_mesh((8,), ("client",))
+            mesh = make_mesh((8,), ("client",))
             ov = topology.expander_overlay(8, 4, seed=0)
             spec = gossip.make_gossip_spec(ov)
             r = np.random.default_rng(0)
@@ -170,9 +170,9 @@ class TestPackedGossipParity:
             import numpy as np, jax, jax.numpy as jnp
             from jax.sharding import NamedSharding, PartitionSpec as P
             from repro.core import gossip, topology
-            from repro.launch.mesh import shard_map
+            from repro.launch.mesh import make_mesh, shard_map
 
-            mesh = jax.make_mesh((8,), ("client",))
+            mesh = make_mesh((8,), ("client",))
             ov = topology.expander_overlay(8, 4, seed=1)
             spec = gossip.make_gossip_spec(ov)
             r = np.random.default_rng(3)
@@ -210,9 +210,9 @@ class TestPackedGossipParity:
             import numpy as np, jax, jax.numpy as jnp
             from jax.sharding import NamedSharding, PartitionSpec as P
             from repro.core import gossip, packing, topology
-            from repro.launch.mesh import shard_map
+            from repro.launch.mesh import make_mesh, shard_map
 
-            mesh = jax.make_mesh((4, 2), ("client", "fsdp"))
+            mesh = make_mesh((4, 2), ("client", "fsdp"))
             ov = topology.expander_overlay(4, 2, seed=0)
             spec = gossip.make_gossip_spec(ov)
             r = np.random.default_rng(0)
@@ -258,9 +258,9 @@ class TestPackedAliveMaskParity:
             import numpy as np, jax, jax.numpy as jnp
             from jax.sharding import NamedSharding, PartitionSpec as P
             from repro.core import gossip, topology
-            from repro.launch.mesh import shard_map
+            from repro.launch.mesh import make_mesh, shard_map
 
-            mesh = jax.make_mesh((8,), ("client",))
+            mesh = make_mesh((8,), ("client",))
             ov = topology.expander_overlay(8, 4, seed=0)
             spec = gossip.make_gossip_spec(ov)
             m = ov.mixing_matrix()
@@ -317,9 +317,9 @@ class TestPackedAliveMaskParity:
             import numpy as np, jax, jax.numpy as jnp
             from jax.sharding import NamedSharding, PartitionSpec as P
             from repro.core import gossip, topology
-            from repro.launch.mesh import shard_map
+            from repro.launch.mesh import make_mesh, shard_map
 
-            mesh = jax.make_mesh((8,), ("client",))
+            mesh = make_mesh((8,), ("client",))
             ov = topology.expander_overlay(8, 4, seed=1)
             spec = gossip.make_gossip_spec(ov)
             m = ov.mixing_matrix()
@@ -452,9 +452,9 @@ class TestPackedDelayedGossip:
             import numpy as np, jax, jax.numpy as jnp
             from jax.sharding import NamedSharding, PartitionSpec as P
             from repro.core import gossip, packing, topology
-            from repro.launch.mesh import shard_map
+            from repro.launch.mesh import make_mesh, shard_map
 
-            mesh = jax.make_mesh((8,), ("client",))
+            mesh = make_mesh((8,), ("client",))
             ov = topology.expander_overlay(8, 4, seed=0)
             spec = gossip.make_gossip_spec(ov)
             r = np.random.default_rng(0)
@@ -508,9 +508,9 @@ class TestPackedDelayedGossip:
             import numpy as np, jax, jax.numpy as jnp
             from jax.sharding import NamedSharding, PartitionSpec as P
             from repro.core import gossip, packing, topology
-            from repro.launch.mesh import shard_map
+            from repro.launch.mesh import make_mesh, shard_map
 
-            mesh = jax.make_mesh((8,), ("client",))
+            mesh = make_mesh((8,), ("client",))
             ov = topology.expander_overlay(8, 4, seed=1)
             spec = gossip.make_gossip_spec(ov)
             r = np.random.default_rng(3)
@@ -570,7 +570,8 @@ class TestPackedCollectiveCount:
             from repro.launch import steps
             from repro.models import params as P
 
-            mesh = jax.make_mesh((4, 4), ("data", "model"))
+            from repro.launch.mesh import make_mesh
+            mesh = make_mesh((4, 4), ("data", "model"))
             cfg = registry.reduced("qwen2.5-3b")  # single-dtype param tree
             shape = ShapeConfig("t", 64, 8, "train")
             counts, texts = {}, {}
@@ -628,7 +629,8 @@ class TestPackedCollectiveCount:
             from repro.core import gossip
             from repro.models import params as P
 
-            mesh = jax.make_mesh((4, 4), ("data", "model"))
+            from repro.launch.mesh import make_mesh
+            mesh = make_mesh((4, 4), ("data", "model"))
             cfg = registry.reduced("qwen2.5-3b")
             shape = ShapeConfig("t", 64, 8, "train")
             par = ParallelConfig(clients_per_pod=4, local_steps=2,

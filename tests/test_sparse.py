@@ -232,7 +232,8 @@ class TestProductionStepSparse:
             from repro.models import params as P
             from repro.telemetry import TraceCounter
 
-            mesh = jax.make_mesh((4, 4), ("data", "model"))
+            from repro.launch.mesh import make_mesh
+            mesh = make_mesh((4, 4), ("data", "model"))
             cfg = registry.reduced("qwen2.5-3b")
             shape = ShapeConfig("t", 64, 8, "train")
             dfl = DFLConfig(degree=2, round_plan="one_peer")

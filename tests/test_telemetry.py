@@ -524,7 +524,8 @@ class TestProductionStepTelemetry:
             from repro.launch import steps
             from repro.models import params as P
 
-            mesh = jax.make_mesh((4, 4), ("data", "model"))
+            from repro.launch.mesh import make_mesh
+            mesh = make_mesh((4, 4), ("data", "model"))
             cfg = registry.reduced("qwen2.5-3b")
             shape = ShapeConfig("t", 64, 8, "train")
             KINDS = ("collective-permute", "all-reduce", "all-gather",
@@ -580,7 +581,8 @@ class TestProductionStepTelemetry:
             from repro.models import params as P
             from repro.telemetry import TraceCounter
 
-            mesh = jax.make_mesh((4, 4), ("data", "model"))
+            from repro.launch.mesh import make_mesh
+            mesh = make_mesh((4, 4), ("data", "model"))
             cfg = registry.reduced("qwen2.5-3b")
             shape = ShapeConfig("t", 16, 4, "train")
             dfl = DFLConfig(degree=2, round_plan="one_peer")
