@@ -89,47 +89,49 @@ def local_round(
 
     Returns (params, velocity, mean_loss).
     """
-    lr = cfg.lr if lr is None else lr
-    upd = update_fn or momentum_update
-    if cfg.reset_momentum:
-        velocity = jax.tree.map(jnp.zeros_like, velocity)
+    with jax.named_scope("dfl.local"):
+        lr = cfg.lr if lr is None else lr
+        upd = update_fn or momentum_update
+        if cfg.reset_momentum:
+            velocity = jax.tree.map(jnp.zeros_like, velocity)
 
-    def grads_of(p, batch):
-        if cfg.grad_accum <= 1:
-            return jax.value_and_grad(loss_fn, has_aux=True)(p, batch)
-        # gradient accumulation: scan over microbatches, average grads —
-        # bounds transient activation memory for the giant MoE shapes
-        mb = jax.tree.map(
-            lambda x: x.reshape((cfg.grad_accum, x.shape[0] // cfg.grad_accum)
-                                + x.shape[1:]), batch)
+        def grads_of(p, batch):
+            if cfg.grad_accum <= 1:
+                return jax.value_and_grad(loss_fn, has_aux=True)(p, batch)
+            # gradient accumulation: scan over microbatches, average grads —
+            # bounds transient activation memory for the giant MoE shapes
+            mb = jax.tree.map(
+                lambda x: x.reshape((cfg.grad_accum, x.shape[0] // cfg.grad_accum)
+                                    + x.shape[1:]), batch)
 
-        adt = cfg.accum_dtype
+            adt = cfg.accum_dtype
 
-        def acc(carry, b):
-            (loss, _aux), g = jax.value_and_grad(loss_fn, has_aux=True)(p, b)
-            gsum, lsum = carry
-            return (jax.tree.map(lambda a, x: a + x.astype(a.dtype), gsum, g),
-                    lsum + loss), None
+            def acc(carry, b):
+                (loss, _aux), g = jax.value_and_grad(loss_fn, has_aux=True)(p, b)
+                gsum, lsum = carry
+                return (jax.tree.map(lambda a, x: a + x.astype(a.dtype), gsum, g),
+                        lsum + loss), None
 
-        zeros = jax.tree.map(
-            lambda w: jnp.zeros(w.shape, jnp.dtype(adt) if adt else w.dtype), p)
-        (gsum, lsum), _ = jax.lax.scan(acc, (zeros, jnp.zeros((), jnp.float32)), mb)
-        inv = 1.0 / cfg.grad_accum
-        return ((lsum * inv, None),
-                jax.tree.map(lambda g, w: (g * inv).astype(w.dtype), gsum, p))
+            zeros = jax.tree.map(
+                lambda w: jnp.zeros(w.shape, jnp.dtype(adt) if adt else w.dtype), p)
+            (gsum, lsum), _ = jax.lax.scan(
+                acc, (zeros, jnp.zeros((), jnp.float32)), mb)
+            inv = 1.0 / cfg.grad_accum
+            return ((lsum * inv, None),
+                    jax.tree.map(lambda g, w: (g * inv).astype(w.dtype), gsum, p))
 
-    def step(carry, batch):
-        p, v = carry
-        (loss, _aux), grads = grads_of(p, batch)
-        if cfg.grad_clip is not None:
-            grads = _clip(grads, cfg.grad_clip)
-        if cfg.weight_decay:
-            grads = jax.tree.map(lambda g, w: g + cfg.weight_decay * w, grads, p)
-        p, v = upd(p, v, grads, lr, cfg.momentum)
-        return (p, v), loss
+        def step(carry, batch):
+            p, v = carry
+            (loss, _aux), grads = grads_of(p, batch)
+            if cfg.grad_clip is not None:
+                grads = _clip(grads, cfg.grad_clip)
+            if cfg.weight_decay:
+                grads = jax.tree.map(lambda g, w: g + cfg.weight_decay * w, grads, p)
+            p, v = upd(p, v, grads, lr, cfg.momentum)
+            return (p, v), loss
 
-    (params, velocity), losses = jax.lax.scan(step, (params, velocity), batches)
-    return params, velocity, jnp.mean(losses)
+        (params, velocity), losses = jax.lax.scan(step, (params, velocity), batches)
+        return params, velocity, jnp.mean(losses)
 
 
 def make_client_round(loss_fn: LossFn, cfg: DFedAvgMConfig,

@@ -874,21 +874,22 @@ class GossipExecutor:
             raise ValueError(
                 "cheby coefficients are a sub_rounds > 1 operand; the "
                 "sub_rounds=1 cell is the sync engine — drop the operand")
-        if cfg.substrate == "dense":
-            return gossip.mix_dense(
-                tree, gossip.gated_mixing_matrix(self.spec, gates, alive))
-        if cfg.substrate == "per_leaf":
-            return self._per_leaf_round(tree)
-        if cfg.substrate == "stacked":
+        with jax.named_scope("dfl.gossip"):
+            if cfg.substrate == "dense":
+                return gossip.mix_dense(
+                    tree, gossip.gated_mixing_matrix(self.spec, gates, alive))
+            if cfg.substrate == "per_leaf":
+                return self._per_leaf_round(tree)
+            if cfg.substrate == "stacked":
+                if cfg.sub_rounds > 1:
+                    return self._stacked_round_cheby(tree, alive, gates, cheby)
+                return self._stacked_round(tree, state, codec_state, alive,
+                                           gates)
+            if cfg.substrate == "blocked":
+                return self._blocked_round(tree, alive, gates)
             if cfg.sub_rounds > 1:
-                return self._stacked_round_cheby(tree, alive, gates, cheby)
-            return self._stacked_round(tree, state, codec_state, alive,
-                                       gates)
-        if cfg.substrate == "blocked":
-            return self._blocked_round(tree, alive, gates)
-        if cfg.sub_rounds > 1:
-            return self._shard_map_round_cheby(tree, alive, gates, cheby)
-        return self._shard_map_round(tree, state, codec_state, alive, gates)
+                return self._shard_map_round_cheby(tree, alive, gates, cheby)
+            return self._shard_map_round(tree, state, codec_state, alive, gates)
 
     # ------------------------------------------------- pipelined state
     def init_state(self, tree: PyTree) -> tuple[jax.Array, ...]:

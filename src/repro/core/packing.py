@@ -193,21 +193,23 @@ def pack_tree(tree: PyTree, spec: PackSpec) -> tuple[jax.Array, ...]:
     if len(leaves) != spec.n_leaves:
         raise ValueError(f"tree has {len(leaves)} leaves, spec packs "
                          f"{spec.n_leaves}")
-    parts: list[list[jax.Array]] = [[] for _ in range(spec.n_buffers)]
-    for leaf, slot in zip(leaves, spec.slots):
-        if leaf.shape != slot.shape or str(jnp.dtype(leaf.dtype)) != slot.dtype:
-            raise ValueError(f"leaf {leaf.shape}/{leaf.dtype} does not match "
-                             f"slot {slot.shape}/{slot.dtype}")
-        parts[slot.buffer].append(leaf.reshape(-1))
-    bufs = []
-    for b in range(spec.n_buffers):
-        flat = (jnp.concatenate(parts[b]) if len(parts[b]) > 1
-                else parts[b][0])
-        total = spec.buffer_rows[b] * LANE
-        if flat.shape[0] < total:
-            flat = jnp.pad(flat, (0, total - flat.shape[0]))
-        bufs.append(flat.reshape(spec.buffer_rows[b], LANE))
-    return tuple(bufs)
+    with jax.named_scope("pack"):
+        parts: list[list[jax.Array]] = [[] for _ in range(spec.n_buffers)]
+        for leaf, slot in zip(leaves, spec.slots):
+            if (leaf.shape != slot.shape
+                    or str(jnp.dtype(leaf.dtype)) != slot.dtype):
+                raise ValueError(f"leaf {leaf.shape}/{leaf.dtype} does not "
+                                 f"match slot {slot.shape}/{slot.dtype}")
+            parts[slot.buffer].append(leaf.reshape(-1))
+        bufs = []
+        for b in range(spec.n_buffers):
+            flat = (jnp.concatenate(parts[b]) if len(parts[b]) > 1
+                    else parts[b][0])
+            total = spec.buffer_rows[b] * LANE
+            if flat.shape[0] < total:
+                flat = jnp.pad(flat, (0, total - flat.shape[0]))
+            bufs.append(flat.reshape(spec.buffer_rows[b], LANE))
+        return tuple(bufs)
 
 
 def unpack_tree(buffers: tuple[jax.Array, ...], spec: PackSpec) -> PyTree:
@@ -215,7 +217,8 @@ def unpack_tree(buffers: tuple[jax.Array, ...], spec: PackSpec) -> PyTree:
     if len(buffers) != spec.n_buffers:
         raise ValueError(f"got {len(buffers)} buffers, spec has "
                          f"{spec.n_buffers}")
-    flats = [b.reshape(-1) for b in buffers]
-    leaves = [flats[s.buffer][s.offset:s.offset + s.size].reshape(s.shape)
-              for s in spec.slots]
-    return jax.tree.unflatten(spec.treedef, leaves)
+    with jax.named_scope("unpack"):
+        flats = [b.reshape(-1) for b in buffers]
+        leaves = [flats[s.buffer][s.offset:s.offset + s.size].reshape(s.shape)
+                  for s in spec.slots]
+        return jax.tree.unflatten(spec.treedef, leaves)

@@ -97,7 +97,7 @@ from repro.core.topology import Overlay
 from repro.launch import mesh as mesh_lib
 from repro.overlay import plan as plan_lib
 from repro.overlay.plan import ActiveSetPlan, RoundPlan
-from repro.telemetry import TelemetryLogger, TraceCounter
+from repro.telemetry import TelemetryLogger, TraceCounter, span
 from repro.telemetry import metrics as telemetry_metrics
 
 PyTree = Any
@@ -559,53 +559,63 @@ class ElasticTrainer:
         """Run one round under the current health mask, the active-set
         plan's participation vector, and the round plan's gates (no rebuilds
         here — all three are data arguments). In delayed mode the in-flight
-        snapshot is threaded through as trainer state."""
-        alive = self.health.alive_mask()
-        if self._masked:
-            # blocked-layout permanent masking: dead-but-unspliceable
-            # clients stay gossip-masked (identity rows) forever
-            alive = alive.copy()
-            alive[sorted(self._masked)] = 0.0
-        if plan_lib.is_subsampling(self.active_plan):
-            # the active set multiplies the GOSSIP mask only — it is
-            # computed here, after the heartbeats were observed, precisely
-            # so it can never feed the HealthTracker (resting != failing)
-            alive = alive * plan_lib.active_for(self.active_plan,
-                                                self.round_no,
-                                                self.overlay.n)
-        alive = jnp.asarray(alive)
-        gates = self.gates_for_round()
-        attack = akey = None
-        if self.attack_plan is not None:
-            # plan columns are in ORIGINAL indices; gather the survivors'
-            # rows so a repaired run keeps each attacker's script
-            vec = self.attack_plan.round_vector(self.round_no)
-            attack = jnp.asarray(vec[:, self._attack_cols])
-            akey = jnp.asarray(
-                np.array([self.attack_seed, self.round_no], np.uint32))
-            if self.logger is not None:
-                for r, ids, mode, mag in self.attack_plan.events:
-                    if r == self.round_no:  # script activates this round
-                        self.logger.event(
-                            "attack", round=self.round_no, mode=mode,
-                            clients=[int(c) for c in ids],
-                            magnitude=float(mag))
-        rnd = self.round_no
-        self.round_no += 1
-        lr = jnp.asarray(lr, jnp.float32)
-        if self.gossip_block:
-            # the blocked round returns params committed to the client
-            # mesh; committing round 0's input there too keeps every round
-            # on one trace (the sharding is part of the traced type)
-            params = jax.device_put(
-                params, NamedSharding(self._gossip_mesh, P("clients")))
+        snapshot is threaded through as trainer state. The round is a
+        ``dfl.round`` span, its host work in ``dfl.*`` leaf spans."""
+        with span("dfl.round", step=self.round_no):
+            return self._step(params, batches, lr)
+
+    def _step(self, params: PyTree, batches: PyTree, lr: float):
+        with span("dfl.operands"):
+            alive = self.health.alive_mask()
+            if self._masked:
+                # blocked-layout permanent masking: dead-but-unspliceable
+                # clients stay gossip-masked (identity rows) forever
+                alive = alive.copy()
+                alive[sorted(self._masked)] = 0.0
+            if plan_lib.is_subsampling(self.active_plan):
+                # the active set multiplies the GOSSIP mask only — it is
+                # computed here, after the heartbeats were observed,
+                # precisely so it can never feed the HealthTracker
+                # (resting != failing)
+                alive = alive * plan_lib.active_for(self.active_plan,
+                                                    self.round_no,
+                                                    self.overlay.n)
+            alive = jnp.asarray(alive)
+            gates = self.gates_for_round()
+            attack = akey = None
+            if self.attack_plan is not None:
+                # plan columns are in ORIGINAL indices; gather the
+                # survivors' rows so a repaired run keeps each attacker's
+                # script
+                vec = self.attack_plan.round_vector(self.round_no)
+                attack = jnp.asarray(vec[:, self._attack_cols])
+                akey = jnp.asarray(
+                    np.array([self.attack_seed, self.round_no], np.uint32))
+                if self.logger is not None:
+                    for r, ids, mode, mag in self.attack_plan.events:
+                        if r == self.round_no:  # script activates this round
+                            self.logger.event(
+                                "attack", round=self.round_no, mode=mode,
+                                clients=[int(c) for c in ids],
+                                magnitude=float(mag))
+            rnd = self.round_no
+            self.round_no += 1
+            lr = jnp.asarray(lr, jnp.float32)
+            if self.gossip_block:
+                # the blocked round returns params committed to the client
+                # mesh; committing round 0's input there too keeps every
+                # round on one trace (the sharding is part of the traced
+                # type)
+                params = jax.device_put(
+                    params, NamedSharding(self._gossip_mesh, P("clients")))
         if self.step_builder is not None:
             # custom builders keep the documented 5-arg StepBuilder contract
             # (screens/attacks with a builder are rejected in __post_init__)
-            return self._round(params, batches, lr, alive, gates)
+            with span("dfl.dispatch"):
+                return self._round(params, batches, lr, alive, gates)
         phase = (self.logger.phase("round") if self.logger is not None
                  else contextlib.nullcontext())
-        with phase:
+        with span("dfl.dispatch"), phase:
             if self._executor.stateful:
                 if self._codec_state is None:  # prime: EF residual zeros
                     self._codec_state = self._executor.init_codec_state(
@@ -637,22 +647,28 @@ class ElasticTrainer:
                                                       alive, gates, attack,
                                                       akey)
         self.last_metrics = metrics
-        if metrics is not None and "clipped" in metrics:
-            # per-sender count of receivers that clipped them this round
-            counts = np.asarray(metrics["clipped"])
-            self.health.observe_suspicion(counts)
-            if self.logger is not None and counts.sum() > 0:
-                self.logger.event("suspicion", round=rnd,
-                                  clipped=[int(c) for c in counts])
-        if self.logger is not None and self.logger.wants_round(rnd):
-            # peeked BEFORE building the record: the loss/metrics floats
-            # are the round's only deliberate device->host sync, and the
-            # sampled logger (round_every > 1) skips it on off-rounds
-            self.logger.round(
-                rnd, loss=float(jnp.mean(losses)),
-                alive=int(np.asarray(alive).sum()),
-                **telemetry_metrics.summarize_metrics(
-                    metrics, n_clients=self.overlay.n))
+        wants = self.logger is not None and self.logger.wants_round(rnd)
+        counts = loss = None
+        with span("dfl.sync"):
+            if metrics is not None and "clipped" in metrics:
+                # per-sender count of receivers that clipped them this round
+                counts = np.asarray(metrics["clipped"])
+            if wants:
+                # peeked BEFORE building the record: the loss/metrics floats
+                # are the round's only deliberate device->host sync, and the
+                # sampled logger (round_every > 1) skips it on off-rounds
+                loss = float(jnp.mean(losses))
+        with span("dfl.record"):
+            if counts is not None:
+                self.health.observe_suspicion(counts)
+                if self.logger is not None and counts.sum() > 0:
+                    self.logger.event("suspicion", round=rnd,
+                                      clipped=[int(c) for c in counts])
+            if wants:
+                self.logger.round(
+                    rnd, loss=loss, alive=int(np.asarray(alive).sum()),
+                    **telemetry_metrics.summarize_metrics(
+                        metrics, n_clients=self.overlay.n))
         return params, losses
 
     def checkpoint(self, rnd: int, params: PyTree) -> None:

@@ -15,7 +15,6 @@ from __future__ import annotations
 import argparse
 import dataclasses
 import json
-import time
 from functools import partial
 from typing import Any, Callable
 
@@ -33,7 +32,7 @@ from repro.launch.steps import build_overlay
 from repro.models import lstm as lstm_model
 from repro.models import params as params_lib
 from repro.overlay import plan as overlay_plan
-from repro.telemetry import TelemetryLogger, TraceCounter
+from repro.telemetry import TelemetryLogger, TraceCounter, span
 from repro.telemetry import metrics as telemetry_metrics
 
 PyTree = Any
@@ -350,6 +349,74 @@ class SimTrainer:
         return params
 
     # ------------------------------------------------------------- train
+    def _run_round(self, params, batch_fn, rnd, lr_fn, log_every, eval_fn,
+                   failure_plan):
+        """One round of :meth:`run`, its host work in ``dfl.*`` spans."""
+        with span("dfl.batch"):
+            batches = batch_fn(rnd)
+        with span("dfl.operands"):
+            if failure_plan is not None:
+                mask = failure_plan.alive_mask(rnd)
+                if not np.array_equal(mask, self._alive):
+                    self.set_stragglers(mask)
+            lr_t = jnp.asarray(lr_fn(rnd), jnp.float32)
+            attack, akey = self._attack_operands(rnd)
+            alive_t = self._alive
+            if overlay_plan.is_subsampling(self.active_plan):
+                # inactive clients are mixed like stragglers (identity
+                # rows) but are only resting — the plan never touches the
+                # persistent straggler mask itself
+                alive_t = alive_t * overlay_plan.active_for(
+                    self.active_plan, rnd, self.overlay.n)
+            alive_t = jnp.asarray(alive_t)
+            gates = self._gates(rnd)
+            cheby = None
+            if (not self._executor.stateful and not self.gossip_delay
+                    and not self.gossip_block and self.gossip_sub_rounds > 1):
+                # coefficients recomputed from the live executor: a repair
+                # rebuilt it with the new spec's lambda, and the fixed (k,)
+                # shape means the refresh never retraces
+                cheby = jnp.asarray(self._executor.cheby_coeffs())
+        with span("dfl.dispatch"):
+            if self._executor.stateful:
+                if self._codec_state is None:  # prime: EF residual zeros
+                    self._codec_state = self._executor.init_codec_state(
+                        params)
+                if self.gossip_delay and self._inflight is None:
+                    self._inflight = self._executor.init_state(params)
+                (params, losses, self._inflight, self._codec_state,
+                 metrics) = self._round_fn(
+                    params, self._inflight, self._codec_state, batches,
+                    lr_t, alive_t, gates, attack, akey)
+            elif self.gossip_delay:
+                if self._inflight is None:  # prime with the initial params
+                    self._inflight = self._executor.init_state(params)
+                params, losses, self._inflight, metrics = self._round_fn(
+                    params, self._inflight, batches, lr_t, alive_t, gates,
+                    attack, akey)
+            elif cheby is not None:
+                params, losses, metrics = self._round_fn(
+                    params, batches, lr_t, alive_t, gates, attack, akey,
+                    cheby)
+            else:
+                params, losses, metrics = self._round_fn(
+                    params, batches, lr_t, alive_t, gates, attack, akey)
+        with span("dfl.sync"):
+            loss = float(jnp.mean(losses))
+        with span("dfl.record"):
+            self.last_metrics = metrics
+            rec = {"round": rnd, "train_loss": loss}
+            rec.update(telemetry_metrics.summarize_metrics(
+                metrics, n_clients=self.overlay.n))
+            if eval_fn is not None and rnd % log_every == 0:
+                rec.update(eval_fn(params))
+            if self.logger is not None and self.logger.wants_round(rnd):
+                self.logger.round(rnd, **{k: v for k, v in rec.items()
+                                          if k != "round"})
+            if self.ckpt is not None:
+                self.ckpt.maybe_save(rnd, params, {"round": rnd})
+        return params, rec
+
     def run(self, params: PyTree, batch_fn: Callable[[int], PyTree],
             rounds: int, lr_fn: Callable[[int], float],
             start_round: int = 0, log_every: int = 1,
@@ -365,65 +432,11 @@ class SimTrainer:
             params = jax.device_put(
                 params, NamedSharding(self._gossip_mesh, P("clients")))
         for rnd in range(start_round, rounds):
-            if failure_plan is not None:
-                mask = failure_plan.alive_mask(rnd)
-                if not np.array_equal(mask, self._alive):
-                    self.set_stragglers(mask)
-            t0 = time.time()
-            batches = batch_fn(rnd)
-            lr_t = jnp.asarray(lr_fn(rnd), jnp.float32)
-            attack, akey = self._attack_operands(rnd)
-            alive_t = self._alive
-            if overlay_plan.is_subsampling(self.active_plan):
-                # inactive clients are mixed like stragglers (identity
-                # rows) but are only resting — the plan never touches the
-                # persistent straggler mask itself
-                alive_t = alive_t * overlay_plan.active_for(
-                    self.active_plan, rnd, self.overlay.n)
-            if self._executor.stateful:
-                if self._codec_state is None:  # prime: EF residual zeros
-                    self._codec_state = self._executor.init_codec_state(
-                        params)
-                if self.gossip_delay and self._inflight is None:
-                    self._inflight = self._executor.init_state(params)
-                (params, losses, self._inflight, self._codec_state,
-                 metrics) = self._round_fn(
-                    params, self._inflight, self._codec_state, batches,
-                    lr_t, jnp.asarray(alive_t), self._gates(rnd),
-                    attack, akey)
-            elif self.gossip_delay:
-                if self._inflight is None:  # prime with the initial params
-                    self._inflight = self._executor.init_state(params)
-                params, losses, self._inflight, metrics = self._round_fn(
-                    params, self._inflight, batches, lr_t,
-                    jnp.asarray(alive_t), self._gates(rnd),
-                    attack, akey)
-            elif not self.gossip_block and self.gossip_sub_rounds > 1:
-                # coefficients recomputed from the live executor: a repair
-                # rebuilt it with the new spec's lambda, and the fixed (k,)
-                # shape means the refresh never retraces
-                params, losses, metrics = self._round_fn(
-                    params, batches, lr_t, jnp.asarray(alive_t),
-                    self._gates(rnd), attack, akey,
-                    jnp.asarray(self._executor.cheby_coeffs()))
-            else:
-                params, losses, metrics = self._round_fn(
-                    params, batches, lr_t, jnp.asarray(alive_t),
-                    self._gates(rnd), attack, akey)
-            self.last_metrics = metrics
-            rec = {"round": rnd,
-                   "train_loss": float(jnp.mean(losses)),
-                   "seconds": round(time.time() - t0, 3)}
-            rec.update(telemetry_metrics.summarize_metrics(
-                metrics, n_clients=self.overlay.n))
-            if eval_fn is not None and rnd % log_every == 0:
-                rec.update(eval_fn(params))
+            with span("dfl.round", step=rnd):
+                params, rec = self._run_round(params, batch_fn, rnd, lr_fn,
+                                              log_every, eval_fn,
+                                              failure_plan)
             history.append(rec)
-            if self.logger is not None and self.logger.wants_round(rnd):
-                self.logger.round(rnd, **{k: v for k, v in rec.items()
-                                          if k != "round"})
-            if self.ckpt is not None:
-                self.ckpt.maybe_save(rnd, params, {"round": rnd})
         return params, history
 
 

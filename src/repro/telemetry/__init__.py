@@ -20,14 +20,15 @@ counters. This package makes them one queryable layer:
   **host** side. One structured JSONL logger (:class:`TelemetryLogger`)
   both trainers thread through: round records, compile/retrace events via
   the one shared :class:`TraceCounter`, repair/quarantine/splice records,
-  attack activations, per-phase wall-clock.
+  attack activations, per-phase wall-clock; and :func:`span`, the host
+  spans both trainers mark each round with on the profiler's timeline.
 * :mod:`repro.telemetry.report` — merge the per-bench
   ``experiments/bench/*.json`` records and run JSONL logs into one summary
   (wire bytes/round per codec, rounds/sec per cell, retrace counts,
   consensus trajectory) — the single CI artifact.
 """
 from repro.telemetry.events import EVENT_KINDS, TraceCounter
-from repro.telemetry.log import TelemetryLogger, read_jsonl
+from repro.telemetry.log import TelemetryLogger, read_jsonl, span
 from repro.telemetry.metrics import (RoundMetrics, TelemetryConfig,
                                      summarize_metrics)
 from repro.telemetry.report import build_summary
@@ -40,5 +41,6 @@ __all__ = [
     "TraceCounter",
     "build_summary",
     "read_jsonl",
+    "span",
     "summarize_metrics",
 ]

@@ -14,9 +14,16 @@ manager::
 ``round`` folds the phase seconds accumulated since the previous round
 record into the emitted record (``{"phases": {name: seconds}}``) — the
 local-step vs gossip vs host breakdown is whatever phases the caller
-brackets. ``phase(..., profile=True)`` additionally wraps the block in a
-``jax.profiler.TraceAnnotation`` so the same names show up on a profiler
-timeline when one is being captured (a no-op otherwise).
+brackets. Every phase is also a :func:`span`, so the same names show up on
+a profiler timeline when one is being captured.
+
+:func:`span` is the program's one host-span helper: a
+``jax.profiler.TraceAnnotation`` (or, given ``step``, a
+``StepTraceAnnotation``), timed on the profiler's clock beside the device
+operations and close to free while no profiler runs. The trainers mark each
+round ``dfl.round`` (its step number is the round index) and its host work
+``dfl.batch`` / ``dfl.operands`` / ``dfl.dispatch`` / ``dfl.sync`` /
+``dfl.record``.
 
 ``round_every=k`` samples the round records: only every k-th round is
 emitted (``rnd % k == 0``), and both trainers consult ``wants_round``
@@ -32,9 +39,19 @@ import json
 import time
 from typing import Any, IO
 
+import jax
+
 from repro.telemetry.events import validate_event
 
-__all__ = ["TelemetryLogger", "read_jsonl"]
+__all__ = ["TelemetryLogger", "read_jsonl", "span"]
+
+
+def span(name: str, step: int | None = None):
+    """A named host span on the profiler's timeline; with ``step``, a step
+    span whose ``step_num`` is ``step`` (a round index)."""
+    if step is None:
+        return jax.profiler.TraceAnnotation(name)
+    return jax.profiler.StepTraceAnnotation(name, step_num=step)
 
 
 def read_jsonl(path: str) -> list[dict]:
@@ -104,18 +121,11 @@ class TelemetryLogger:
 
     # ------------------------------------------------------------- phases
     @contextlib.contextmanager
-    def phase(self, name: str, profile: bool = False):
-        """Accumulate wall-clock for ``name`` until the next :meth:`round`.
-        ``profile=True`` also annotates a captured profiler timeline."""
-        ctx = contextlib.nullcontext()
-        if profile:
-            try:
-                import jax
-                ctx = jax.profiler.TraceAnnotation(name)
-            except Exception:  # profiler unavailable: timing still works
-                ctx = contextlib.nullcontext()
+    def phase(self, name: str):
+        """Accumulate wall-clock for ``name`` until the next :meth:`round`;
+        the block is also a :func:`span` of that name."""
         t0 = time.perf_counter()
-        with ctx:
+        with span(name):
             yield
         self._phases[name] = (self._phases.get(name, 0.0)
                               + time.perf_counter() - t0)

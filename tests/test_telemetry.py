@@ -18,6 +18,7 @@ rotation on ONE executable, and the step's params output is bitwise
 independent of the telemetry flag.
 """
 import json
+import re
 import subprocess
 import sys
 import textwrap
@@ -677,3 +678,91 @@ class TestRoundSampling:
     def test_round_every_validated(self):
         with pytest.raises(ValueError, match="round_every"):
             TelemetryLogger(round_every=0)
+
+
+# ------------------------------------------------ program scopes and spans
+def _scoped_loss(p, b):
+    return (jnp.mean(jnp.square(p["w"] - b["t"]))
+            + jnp.mean(jnp.square(p["b"])), {})
+
+
+def _host_spans(trace_dir, prefix):
+    """[name, start_ns, end_ns, step_num] of the host spans in a trace."""
+    import glob
+    import os
+
+    from jax.profiler import ProfileData
+    path = glob.glob(os.path.join(str(trace_dir), "**", "*.xplane.pb"),
+                     recursive=True)[0]
+    out = []
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                out.extend([e.name, e.start_ns, e.end_ns,
+                            dict(e.stats).get("step_num")]
+                           for e in line.events if e.name.startswith(prefix))
+    return sorted(out, key=lambda s: s[1])
+
+
+LEAF_SPANS = ["dfl.operands", "dfl.dispatch", "dfl.sync", "dfl.record"]
+
+
+class TestScopesAndSpans:
+    def test_round_fn_carries_the_layer_scopes(self):
+        """The compiled round names its layers in every instruction's
+        op_name: the local phase's while loop under dfl.local, the mix
+        under dfl.gossip, the packing copies under dfl.gossip/pack."""
+        from repro.launch.train import SimTrainer
+        n = 8
+        tr = SimTrainer(overlay=topology.expander_overlay(n, 4, seed=0),
+                        loss_fn=_scoped_loss,
+                        dcfg=dfedavg.DFedAvgMConfig(local_steps=2, lr=0.3,
+                                                    momentum=0.5))
+        params = {"w": jnp.zeros((n, 5)), "b": jnp.ones((n, 3))}
+        text = tr.round_fn.lower(
+            params, {"t": jnp.ones((n, 2, 5))}, jnp.float32(0.1),
+            jnp.ones(n, jnp.float32), tr._gates(0), None,
+            None).compile().as_text()
+        ops = dict(re.findall(
+            r'^\s*(?:ROOT )?%([\w.-]+) = .*op_name="([^"]*)"', text, re.M))
+        whiles = [v for k, v in ops.items() if k.startswith("while")]
+        assert whiles and all("dfl.local" in v for v in whiles)
+        assert any(v.endswith("dfl.gossip/mul") for v in ops.values())
+        assert any("dfl.gossip/vmap(pack)/" in v for v in ops.values())
+        assert any("dfl.gossip/vmap(unpack)/" in v for v in ops.values())
+        assert not any("dfl.gossip" in v for v in whiles)
+
+    def test_elastic_round_spans(self, tmp_path):
+        """Each ElasticTrainer.step is a dfl.round step span (step number =
+        round index) around its leaf spans, in order; the logger's phase
+        is a span too and still times the JSONL phase."""
+        n, dim = 8, 3
+        trainer = ElasticTrainer(
+            overlay=topology.expander_overlay(n, 4, seed=0),
+            loss_fn=lambda p, b: (jnp.mean(jnp.square(p["w"] - b["t"])), {}),
+            dcfg=dfedavg.DFedAvgMConfig(local_steps=2, lr=0.3, momentum=0.5),
+            logger=TelemetryLogger())
+        params = {"w": jnp.zeros((n, dim))}
+        batch = {"t": jnp.ones((n, 2, dim))}
+        params, _ = trainer.step(params, batch, 0.3)   # compile outside
+        with jax.profiler.trace(str(tmp_path)):
+            for _ in range(2):
+                params, _ = trainer.step(params, batch, 0.3)
+        spans = _host_spans(tmp_path, "dfl.")
+        rounds = [s for s in spans if s[0] == "dfl.round"]
+        assert [int(s[3]) for s in rounds] == [1, 2]
+        for r in rounds:
+            inside = [s[0] for s in spans if s[0] != "dfl.round"
+                      and r[1] <= s[1] and s[2] <= r[2]]
+            assert inside == LEAF_SPANS
+        assert len(_host_spans(tmp_path, "round")) == 2    # the phase
+        assert all("round" in r["phases"]
+                   for r in trainer.logger.of_kind("round"))
+
+    def test_phase_is_a_span(self, tmp_path):
+        log = TelemetryLogger()
+        with jax.profiler.trace(str(tmp_path)):
+            with log.phase("gossip"):
+                pass
+        assert [s[0] for s in _host_spans(tmp_path, "gossip")] == ["gossip"]
+        assert "gossip" in log.round(0, loss=0.0)["phases"]
