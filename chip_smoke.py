@@ -18,8 +18,9 @@ batch 8, seq 64. Two engine cells:
 Four chips run only what exists across chips:
 
 * ``shard_map`` — ``launch.steps.build_train_step`` on the device mesh,
-  one ``internvl2-1b`` client (published widths, 4 x 1024 tokens) per chip
-  on a ring, ``f32`` and ``int8_block``: d collective-permutes per round
+  one ``internvl2-1b`` client (published widths, 4 x 1024 positions, the
+  first 256 of them 4096-wide vision features through the projector) per
+  chip on a ring, ``f32`` and ``int8_block``: d collective-permutes per round
   into ``gossip_mix_2d`` / ``dequant_accumulate_2d_blockwise``;
 * ``blocked`` — 512 char-LSTM clients, 128 per chip (``--gossip-block
   128``), whole-block permutes between chips.

@@ -1,0 +1,11 @@
+"""Device milliseconds per round in collectives (the gossip engine's
+permutes over ICI), synchronous and asynchronous, averaged over the chips:
+the trace summary's ``collective_s`` over the traced window's rounds."""
+UNIT, BETTER, SOURCE = "ms", "lower", "device_trace"
+LAYER, MOVES = "gossip engine", "tokens_per_s"
+
+
+def read(run):
+    if run.trace is None or not run.rounds or not run.trace["collective_s"]:
+        return None
+    return run.trace["collective_s"] / run.rounds * 1e3

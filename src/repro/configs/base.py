@@ -81,6 +81,10 @@ class ModelConfig:
     rwkv: RWKVConfig | None = None
     frontend: Literal["none", "audio_stub", "vision_stub"] = "none"
     stub_prefix: int = 0                   # precomputed frontend embeddings prepended
+    # vision_stub: width of the frozen vision encoder's output features,
+    # which the trainable projector maps to d_model (InternVL2: 4096, the
+    # pixel-shuffled InternViT-300M output)
+    vision_feature_dim: int = 0
     post_norm: bool = False                # gemma2: post-attn/post-ffn norms
     scale_embeddings: bool = False         # gemma2: x *= sqrt(d_model)
     norm_plus_one: bool = False            # gemma2: rmsnorm scale = (1 + w)
@@ -93,6 +97,13 @@ class ModelConfig:
     @property
     def resolved_head_dim(self) -> int:
         return self.head_dim if self.head_dim is not None else self.d_model // self.n_heads
+
+    @property
+    def prefix_width(self) -> int:
+        """Width of the precomputed frontend features (``prefix_embeds``):
+        the vision encoder's features for vision_stub, else d_model."""
+        return (self.vision_feature_dim if self.frontend == "vision_stub"
+                else self.d_model)
 
     @property
     def padded_vocab(self) -> int:
@@ -126,7 +137,12 @@ class ModelConfig:
             n_attn = self.n_layers // s.attn_every
             shared = (d * (qd + 2 * kvd) + qd * d + 3 * d * self.d_ff + 2 * d)
             return total + self.n_layers * per_mamba + shared  # shared counted once
-        # transformer
+        # transformer: + the final norm, and the vision projector (LayerNorm
+        # with bias, Linear f->d, Linear d->d, both with bias)
+        total += d
+        if self.frontend == "vision_stub":
+            f = self.vision_feature_dim
+            total += 2 * f + f * d + d + d * d + d
         attn = d * (qd + 2 * kvd) + qd * d
         if self.qkv_bias:
             attn += qd + 2 * kvd

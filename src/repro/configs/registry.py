@@ -65,11 +65,15 @@ ARCHS: dict[str, ModelConfig] = {
         name="rwkv6-1.6b", family="rwkv", n_layers=24, d_model=2048,
         n_heads=32, n_kv_heads=32, d_ff=7168, vocab=65536,
         rwkv=RWKVConfig(head_dim=64), supports_500k=True),
-    # [vlm]  arXiv:2404.16821 — InternViT(stub) + InternLM2 backbone
+    # [vlm]  hf:OpenGVLab/InternVL2-1B (arXiv:2404.16821) — frozen
+    # InternViT-300M-448px (stubbed: its 256 pixel-shuffled 4096-wide
+    # features per tile are inputs) -> mlp1 projector -> Qwen2-0.5B-Instruct
+    # (GQA + QKV bias, RoPE base 1e6, untied head)
     "internvl2-1b": ModelConfig(
         name="internvl2-1b", family="transformer", n_layers=24, d_model=896,
-        n_heads=14, n_kv_heads=2, d_ff=4864, vocab=151655,
-        frontend="vision_stub", stub_prefix=256),
+        n_heads=14, n_kv_heads=2, d_ff=4864, vocab=151655, qkv_bias=True,
+        rope_theta=1_000_000.0, frontend="vision_stub", stub_prefix=256,
+        vision_feature_dim=4096),
     # [hybrid]  arXiv:2411.15242 — Mamba2 backbone + shared attention
     "zamba2-2.7b": ModelConfig(
         name="zamba2-2.7b", family="zamba", n_layers=54, d_model=2560,
@@ -111,6 +115,8 @@ def reduced(arch_id: str) -> ModelConfig:
         kw["local_window"] = 32
     if cfg.stub_prefix:
         kw["stub_prefix"] = 8
+    if cfg.vision_feature_dim:
+        kw["vision_feature_dim"] = 32
     return dataclasses.replace(cfg, **kw)
 
 

@@ -49,4 +49,5 @@ def sgdm_2d(w: jax.Array, v: jax.Array, g: jax.Array, scalars: jax.Array, *,
         out_shape=[jax.ShapeDtypeStruct((rows, LANE), w.dtype),
                    jax.ShapeDtypeStruct((rows, LANE), v.dtype)],
         interpret=interpret,
+        name="fused_sgdm",
     )(w, v, g, scalars)

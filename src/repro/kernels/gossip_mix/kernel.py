@@ -179,12 +179,14 @@ def gossip_mix_2d(stack: jax.Array, weights: jax.Array,
         return pl.pallas_call(
             _mix_kernel, grid=grid, in_specs=[stack_spec, vec_spec],
             out_specs=out_spec, out_shape=out_shape, interpret=interpret,
+            name="gossip_mix",
         )(stack, w2)
     a2 = alive.reshape(k, 1).astype(jnp.float32)
     return pl.pallas_call(
         _mix_alive_kernel, grid=grid,
         in_specs=[stack_spec, vec_spec, vec_spec],
         out_specs=out_spec, out_shape=out_shape, interpret=interpret,
+        name="gossip_mix_alive",
     )(stack, w2, a2)
 
 

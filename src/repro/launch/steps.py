@@ -236,7 +236,8 @@ def build_train_step(cfg: ModelConfig, shape: ShapeConfig, base_mesh: Mesh,
     }
     if cfg.stub_prefix:
         batch_specs["prefix_embeds"] = jax.ShapeDtypeStruct(
-            bshape + (local_b, cfg.stub_prefix, cfg.d_model), jnp.dtype(cfg.dtype))
+            bshape + (local_b, cfg.stub_prefix, cfg.prefix_width),
+            jnp.dtype(cfg.dtype))
         batch_pspec["prefix_embeds"] = P(client_spec, None, ("fsdp", "dp"), None, None)
 
     dcfg = dfedavg.DFedAvgMConfig(

@@ -86,14 +86,14 @@ class ModelAPI:
                      "labels": jax.ShapeDtypeStruct((b, s), i32)}
             if self.cfg.stub_prefix:
                 specs["prefix_embeds"] = jax.ShapeDtypeStruct(
-                    (b, self.cfg.stub_prefix, self.cfg.d_model),
+                    (b, self.cfg.stub_prefix, self.cfg.prefix_width),
                     jnp.dtype(self.cfg.dtype))
             return specs
         if shape.kind == "prefill":
             specs = {"tokens": jax.ShapeDtypeStruct((b, s), i32)}
             if self.cfg.stub_prefix:
                 specs["prefix_embeds"] = jax.ShapeDtypeStruct(
-                    (b, self.cfg.stub_prefix, self.cfg.d_model),
+                    (b, self.cfg.stub_prefix, self.cfg.prefix_width),
                     jnp.dtype(self.cfg.dtype))
             return specs
         # decode: one new token against a cache of size seq_len

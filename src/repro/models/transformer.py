@@ -6,8 +6,10 @@ Features, all driven by ModelConfig:
     (gemma2), tied embeddings, sinusoidal or rotary positions (musicgen),
     pre/post norms, (1+w) rmsnorm and embedding scaling (gemma2);
   * dense GLU FFN or routed MoE (grok-1, kimi-k2) with shared experts;
-  * stub modality frontends: `stub_prefix` precomputed embeddings are
-    prepended over the token embeddings (internvl2 vision, musicgen audio);
+  * stub modality frontends: `stub_prefix` precomputed embeddings take the
+    first positions in place of the token embeddings (musicgen audio); the
+    vision stub (internvl2) takes the frozen encoder's features and maps
+    them to d_model through InternVL2's trainable `mlp1` projector;
   * scan-over-layers with stacked parameters (compile-time O(1) in depth);
     the local/global pattern scans over layer *pairs* so the window is a
     static argument (no doubled attention compute);
@@ -84,6 +86,16 @@ def param_struct(cfg: ModelConfig) -> PyTree:
     }
     if not cfg.tie_embeddings:
         struct["head"] = Leaf((d, v), ("embed", "vocab"), dt)
+    if cfg.frontend == "vision_stub":
+        f = cfg.vision_feature_dim
+        struct["vision_proj"] = {
+            "ln_scale": Leaf((f,), (None,), dt, "ones"),
+            "ln_bias": Leaf((f,), (None,), dt, "zeros"),
+            "w1": Leaf((f, d), (None, "embed"), dt),
+            "b1": Leaf((d,), ("embed",), dt, "zeros"),
+            "w2": Leaf((d, d), ("embed", None), dt),
+            "b2": Leaf((d,), (None,), dt, "zeros"),
+        }
     return struct
 
 
@@ -190,12 +202,25 @@ def _scan_blocks(x, blocks, positions, cfg: ModelConfig, remat: bool,
     return lax.scan(body, x, blocks)
 
 
+def vision_projector(p, feats):
+    """InternVL2's ``mlp1``: LayerNorm (eps 1e-5, with bias) -> Linear f->d
+    -> GELU (exact) -> Linear d->d. feats (B, P, f) -> (B, P, d)."""
+    with jax.named_scope("vision_proj"):
+        h = L.layer_norm(feats, p["ln_scale"], p["ln_bias"], eps=1e-5)
+        h = jnp.einsum("bpf,fd->bpd", h, p["w1"]) + p["b1"].astype(h.dtype)
+        h = jax.nn.gelu(h.astype(F32), approximate=False).astype(feats.dtype)
+        return jnp.einsum("bpd,de->bpe", h, p["w2"]) + p["b2"].astype(h.dtype)
+
+
 def _embed_inputs(params, tokens, cfg: ModelConfig, prefix_embeds=None):
     x = L.embed_lookup(params["embed"], tokens)
     if cfg.stub_prefix:
         assert prefix_embeds is not None, f"{cfg.name} needs frontend embeddings"
         p = cfg.stub_prefix
-        x = jnp.concatenate([prefix_embeds.astype(x.dtype), x[:, p:]], axis=1)
+        prefix = prefix_embeds.astype(x.dtype)
+        if cfg.frontend == "vision_stub":
+            prefix = vision_projector(params["vision_proj"], prefix)
+        x = jnp.concatenate([prefix, x[:, p:]], axis=1)
     if cfg.scale_embeddings:
         x = (x.astype(F32) * np.sqrt(cfg.d_model)).astype(x.dtype)
     if cfg.pos_emb == "sinusoidal":

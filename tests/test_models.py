@@ -17,8 +17,8 @@ def _batch(cfg, b=2, s=32):
     toks = jax.random.randint(jax.random.key(1), (b, s), 0, cfg.vocab)
     batch = {"tokens": toks, "labels": toks}
     if cfg.stub_prefix:
-        batch["prefix_embeds"] = jnp.zeros((b, cfg.stub_prefix, cfg.d_model),
-                                           jnp.dtype(cfg.dtype))
+        batch["prefix_embeds"] = jnp.zeros(
+            (b, cfg.stub_prefix, cfg.prefix_width), jnp.dtype(cfg.dtype))
     return batch
 
 
@@ -72,7 +72,7 @@ def test_decode_matches_forward(arch):
     params = api.init_params(RNG)
     B, S = 2, 24
     toks = jax.random.randint(jax.random.key(2), (B, S + 1), 0, cfg.vocab)
-    pe = (jnp.zeros((B, cfg.stub_prefix, cfg.d_model), jnp.float32)
+    pe = (jnp.zeros((B, cfg.stub_prefix, cfg.prefix_width), jnp.float32)
           if cfg.stub_prefix else None)
 
     full = api.forward(params, toks, **({"prefix_embeds": pe} if pe is not None else {}))
