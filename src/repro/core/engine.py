@@ -11,7 +11,9 @@ The engine factors the round into:
 
 * **WireCodec** — what travels on the wire and how it folds back into the
   mixing reduction. ``"f32"`` ships the packed buffer unchanged and reduces
-  through the fused ``gossip_mix_2d`` stack pass; ``"int8"`` /
+  through the fused ``gossip_mix_2d`` pass on ``shard_map`` (the stacked
+  rounds mix its rows with an f32 multiply-add, on the bare leaves when no
+  snapshot is carried); ``"int8"`` /
   ``"int8_block"`` quantize through the Pallas quantize kernels, fold the
   f32 scale(s) INTO the shipped int8 buffer (one collective per schedule —
   ``fold_scale(s)_into_wire``), and fold each received wire into the
@@ -102,10 +104,6 @@ __all__ = [
 
 PyTree = Any
 
-# the stacked/blocked mix einsums run at full f32: a TPU's default f32 dot
-# takes bf16 passes, which would break parity with the f32 oracles
-_EXACT = jax.lax.Precision.HIGHEST
-
 SUBSTRATES = ("shard_map", "stacked", "blocked", "per_leaf", "dense")
 SCREENS = ("none", "norm_clip", "trimmed_mean")
 # the cells the delay and screen layers are wired for; "blocked" joins when
@@ -183,7 +181,7 @@ class GossipEngineConfig:
         at least one live value always survives; 0 = renormalized mean).
       block: B, simulated clients per device — required (>= 1, dividing
         ``n_clients``) on the "blocked" substrate, must stay 0 elsewhere.
-        The blocked cell runs the stacked gather/einsum round on a
+        The blocked cell runs the stacked gather/multiply-add round on a
         device-local ``(B, ...)`` slice under shard_map; cross-device
         schedule edges ship whole per-device wire blocks via the
         :class:`~repro.core.gossip.BlockedSpec` partition baked at build
@@ -440,11 +438,40 @@ def _clip_factors(r2, lim):
     return jnp.where(r2 > lim, jnp.sqrt(lim / jnp.maximum(r2, 1e-30)), 1.0)
 
 
+def _weighted_rows(w, self_rows, srcs):
+    """The stacked family's weighted reduce over client-stacked rows:
+    ``w[:, 0] * self_rows + w[:, 1] * srcs[0] + ... + w[:, d] *
+    srcs[d - 1]``, in f32 in schedule order with the (n, d+1) table ``w``
+    broadcast over the trailing axes, cast back to ``self_rows``' dtype.
+    Each row term is read once; no (n, d+1, ...) stack is built. Every
+    stacked-family round reduces through here, so packed buffers and bare
+    leaves of the same rows mix bit for bit alike.
+
+    Each product is rounded to f32 before it is summed. XLA:CPU lets LLVM
+    fuse a multiply into the add that consumes it, and which of two
+    products it fuses depends on the code around it, so a bare chain of
+    multiply-adds can differ by an ulp between layouts. Adding an opaque
+    +0 to each product leaves its value as it is, and leaves no add in the
+    sum with a multiply to absorb."""
+    with jax.named_scope("mix"):
+        zero = jax.lax.optimization_barrier(jnp.zeros((), jnp.float32))
+        lead = (w.shape[0],) + (1,) * (self_rows.ndim - 1)
+
+        def term(k, rows):
+            return w[:, k].reshape(lead) * rows.astype(jnp.float32) + zero
+
+        acc = term(0, self_rows)
+        for s, src in enumerate(srcs):
+            acc = acc + term(1 + s, src)
+        return acc.astype(self_rows.dtype)
+
+
 class _F32Codec:
-    """Identity wire: ship the packed buffer, reduce via the fused stack
-    pass (``gossip_mix_2d``). The encode is literally the buffer, so the
-    delayed snapshot is the packed fresh state — byte-identical to the
-    pre-refactor delayed executors."""
+    """Identity wire: ship the packed buffer; on ``shard_map`` reduce via
+    the fused ``gossip_mix_2d`` pass. The encode is literally the buffer, so
+    the delayed snapshot is the packed fresh state. The stacked family never
+    calls :meth:`reduce` for this codec: it mixes through
+    :func:`_weighted_rows`, on the bare leaves when no snapshot is carried."""
 
     name = "f32"
     identity_wire = True   # wire IS the packed buffer (no encode/decode)
@@ -1320,13 +1347,16 @@ class GossipExecutor:
              if alive is None and gates is None
              else gossip.alive_weight_table(spec, alive, gates))
         gathers = [jnp.asarray(rf) for rf in spec.recv_from]
-        fresh = jax.vmap(lambda t: packing.pack_tree(t, pack_spec))(tree)
+        # an identity wire with no snapshot needs no packed layout: the
+        # round gathers and mixes the leaves themselves
+        leafwise = (codec.identity_wire and not self.stateful
+                    and not cfg.delay)
+        fresh, sq, rebuild = self._stacked_rows(tree, pack_spec, leafwise)
         metrics, tcontrib = self._stacked_metrics_init(alive, gates)
         resid = jnp.zeros((spec.n_clients,), jnp.float32)
-        sq = jax.vmap(self._sq(pack_spec))
         out_bufs, new_state, new_cstate = [], [], []
         for b, buf in enumerate(fresh):
-            n_blocks = pack_spec.buffer_blocks(b)
+            n_blocks = None if leafwise else pack_spec.buffer_blocks(b)
 
             def enc(x, b=b):
                 return codec.encode(x, n_blocks=n_blocks,
@@ -1357,23 +1387,18 @@ class GossipExecutor:
                 src = jax.vmap(dec)(wire)
             # self row stays the FRESH full-precision buffer; only the
             # gathered neighbor rows go through the codec / the snapshot
-            stack = jnp.stack([buf] + [jnp.take(src, idx, axis=0)
-                                       for idx in gathers], axis=1)
-            out = jnp.einsum("nk,nk...->n...", w, stack.astype(jnp.float32),
-                             precision=_EXACT)
-            out_bufs.append(out.astype(buf.dtype))
+            srcs = [jnp.take(src, idx, axis=0) for idx in gathers]
+            out_bufs.append(_weighted_rows(w, buf, srcs))
             if tel is not None and tel.consensus:
-                for s in range(len(gathers)):
+                for s, g in enumerate(srcs):
                     resid = resid + tcontrib[:, 1 + s] * sq(
-                        stack[:, 1 + s].astype(jnp.float32)
-                        - buf.astype(jnp.float32))
+                        g.astype(jnp.float32) - buf.astype(jnp.float32))
             if cfg.delay and not self.stateful:
                 new_state.append(buf if codec.identity_wire
                                  else jax.vmap(enc)(buf))
         if tel is not None and tel.consensus:
             metrics["resid_sqnorm"] = resid
-        mixed = jax.vmap(lambda bs: packing.unpack_tree(bs, pack_spec))(
-            tuple(out_bufs))
+        mixed = rebuild(out_bufs)
         ret = (mixed,)
         if cfg.delay:
             ret = ret + (tuple(new_state),)
@@ -1382,6 +1407,24 @@ class GossipExecutor:
         if tel is not None:
             ret = ret + (metrics,)
         return ret[0] if len(ret) == 1 else ret
+
+    def _stacked_rows(self, tree, pack_spec, leafwise):
+        """What a stacked round mixes: the client-stacked tree's own leaves
+        when ``leafwise``, else its vmapped ``(n, rows, 128)`` pack buffers.
+        Returns ``(rows, sq, rebuild)``: the arrays, the per-client squared
+        norm of an f32 array shaped like one of them, and the map from the
+        mixed arrays back to the tree."""
+        if leafwise:
+            leaves, treedef = jax.tree.flatten(tree)
+
+            def sq(x):
+                return jnp.sum(jnp.square(x).reshape(x.shape[0], -1), axis=1)
+
+            return leaves, sq, lambda out: jax.tree.unflatten(treedef, out)
+        fresh = jax.vmap(lambda t: packing.pack_tree(t, pack_spec))(tree)
+        return (fresh, jax.vmap(self._sq(pack_spec)),
+                lambda out: jax.vmap(
+                    lambda bs: packing.unpack_tree(bs, pack_spec))(tuple(out)))
 
     def _stacked_metrics_init(self, alive, gates):
         """(metrics dict seeded with the degree metrics, contributor table)
@@ -1401,9 +1444,10 @@ class GossipExecutor:
         """Chebyshev multi-round gossip (sub_rounds = k > 1), stacked.
 
         Same contract as :meth:`_shard_map_round_cheby` on the client-
-        stacked substrate: k gather+einsum applications of the one weight
-        table (computed once — the same W each sub-round), the second-order
-        combine in f32, telemetry measured on the first sub-round. The f32
+        stacked substrate: k gather + multiply-add applications of the one
+        weight table (computed once — the same W each sub-round), the
+        second-order combine in f32, telemetry measured on the first
+        sub-round. An identity wire mixes the bare leaves (no pack). The f32
         cell is the dense-oracle reference: it matches
         ``mixing.chebyshev_mix(x, gossip.gated_mixing_matrix(spec, gates,
         alive), cheby)`` to float tolerance."""
@@ -1414,14 +1458,14 @@ class GossipExecutor:
              if alive is None and gates is None
              else gossip.alive_weight_table(spec, alive, gates))
         gathers = [jnp.asarray(rf) for rf in spec.recv_from]
-        fresh = jax.vmap(lambda t: packing.pack_tree(t, pack_spec))(tree)
+        leafwise = codec.identity_wire
+        fresh, sq, rebuild = self._stacked_rows(tree, pack_spec, leafwise)
         metrics, tcontrib = self._stacked_metrics_init(alive, gates)
         resid = jnp.zeros((spec.n_clients,), jnp.float32)
-        sq = jax.vmap(self._sq(pack_spec))
         omg = jnp.asarray(cheby, jnp.float32)
         out_bufs = []
         for b, buf in enumerate(fresh):
-            n_blocks = pack_spec.buffer_blocks(b)
+            n_blocks = None if leafwise else pack_spec.buffer_blocks(b)
 
             def enc(x, n_blocks=n_blocks):
                 return codec.encode(x, n_blocks=n_blocks,
@@ -1440,22 +1484,19 @@ class GossipExecutor:
                 # the gathered neighbor rows go through the codec wire
                 src = (xj if codec.identity_wire
                        else jax.vmap(dec)(jax.vmap(enc)(xj)))
-                stack = jnp.stack([xj] + [jnp.take(src, g, axis=0)
-                                          for g in gathers], axis=1)
-                y = jnp.einsum("nk,nk...->n...", w,
-                               stack.astype(jnp.float32), precision=_EXACT)
+                srcs = [jnp.take(src, g, axis=0) for g in gathers]
+                # an f32 self row keeps y in f32 for the combine below
+                y = _weighted_rows(w, xj.astype(jnp.float32), srcs)
                 if j == 0 and tel is not None and tel.consensus:
-                    for s in range(len(gathers)):
+                    for s, g in enumerate(srcs):
                         resid = resid + tcontrib[:, 1 + s] * sq(
-                            stack[:, 1 + s].astype(jnp.float32)
-                            - xj.astype(jnp.float32))
+                            g.astype(jnp.float32) - xj.astype(jnp.float32))
                 x_next = omg[j] * (y - x_prev) + x_prev
                 x_prev, x_cur = x_cur, x_next
             out_bufs.append(x_cur.astype(buf.dtype))
         if tel is not None and tel.consensus:
             metrics["resid_sqnorm"] = resid
-        mixed = jax.vmap(lambda bs: packing.unpack_tree(bs, pack_spec))(
-            tuple(out_bufs))
+        mixed = rebuild(out_bufs)
         if tel is not None:
             return mixed, metrics
         return mixed
@@ -1465,8 +1506,10 @@ class GossipExecutor:
         the delayed snapshot) are materialized for every buffer first so the
         norm-clip screen can compare whole-model norms; the per-buffer mix
         then runs with either the clip-scaled weight table (norm_clip: the
-        same einsum as the plain round, so an all-ones clip is bitwise
-        identical) or the vmapped trimmed-mean kernel (trimmed_mean).
+        same multiply-add as the plain round, :func:`_weighted_rows`, so an
+        all-ones clip is bitwise identical) or the vmapped trimmed-mean
+        kernel over the (n, d+1, rows, 128) stack (trimmed_mean: its order
+        statistics need every value of an element at once).
 
         Under telemetry, the norm_clip cells emit per-SENDER ``clipped``
         counts of receivers that clipped them this round — the suspicion
@@ -1528,34 +1571,33 @@ class GossipExecutor:
                     counts = counts.at[g].add(flag)
                 metrics["clipped"] = counts
 
-            def mixer(stack):
-                return jnp.einsum("nk,nk...->n...", eff,
-                                  stack.astype(jnp.float32), precision=_EXACT)
+            def mixer(buf, recv):
+                return _weighted_rows(eff, buf, recv)
         else:  # trimmed_mean
             raw, contrib = gossip.raw_contrib_tables(spec, alive, gates)
             trim_u = jnp.maximum(raw, 0.0) * contrib
             trim_live = (contrib > 0.0).astype(jnp.float32)
 
-            def mixer(stack):
+            def mixer(buf, recv):
                 return jax.vmap(
                     lambda st, uu, ll: mix_ops.gossip_mix_trimmed_packed(
                         st, uu, ll, trim=cfg.trim_f,
                         block_rows=pack_spec.block_rows,
-                        impl=cfg.mix_impl))(stack, trim_u, trim_live)
+                        impl=cfg.mix_impl))(
+                    jnp.stack([buf] + recv, axis=1), trim_u,
+                    trim_live).astype(buf.dtype)
         resid = jnp.zeros((spec.n_clients,), jnp.float32)
         vsq = jax.vmap(self._sq(pack_spec))
         out_bufs = []
         for b, buf in enumerate(fresh):
             # self row stays the FRESH full-precision buffer; only the
             # gathered neighbor rows go through the codec / the snapshot
-            stack = jnp.stack([buf] + [jnp.take(srcs[b], idx, axis=0)
-                                       for idx in gathers], axis=1)
-            out_bufs.append(mixer(stack).astype(buf.dtype))
+            recv = [jnp.take(srcs[b], idx, axis=0) for idx in gathers]
+            out_bufs.append(mixer(buf, recv))
             if tel is not None and tel.consensus:
-                for s in range(len(gathers)):
+                for s, g in enumerate(recv):
                     resid = resid + tcontrib[:, 1 + s] * vsq(
-                        stack[:, 1 + s].astype(jnp.float32)
-                        - buf.astype(jnp.float32))
+                        g.astype(jnp.float32) - buf.astype(jnp.float32))
         if tel is not None and tel.consensus:
             metrics["resid_sqnorm"] = resid
         mixed = jax.vmap(lambda bs: packing.unpack_tree(bs, pack_spec))(
@@ -1678,14 +1720,14 @@ class GossipExecutor:
         gathers its source row out of the [local + received] candidate stack
         through the static ``gather_flat`` table (sliced to this device by
         ``axis_index``). The final weighted reduction is the stacked
-        substrate's einsum over the device-local rows of the SAME
-        ``alive_weight_table`` — f32 cells are bit-identical to the stacked
-        reference on the same overlay, and alive / active-set / gate churn
-        stays plain data.
+        substrate's multiply-add (:func:`_weighted_rows`) over the
+        device-local rows of the SAME ``alive_weight_table`` — f32 cells are
+        bit-identical to the stacked reference on the same overlay, and
+        alive / active-set / gate churn stays plain data.
 
         Telemetry (when on) reads the device-local (block,) rows of the
         contributor table and measures residuals off the ALREADY-gathered
-        candidate stack — zero extra collectives, asserted by the HLO
+        candidate rows — zero extra collectives, asserted by the HLO
         guards in tests/test_telemetry.py. The island's out_spec over the
         client device axis concatenates the per-device rows back to the
         (n,)-stacked layout."""
@@ -1738,17 +1780,13 @@ class GossipExecutor:
                     for s in range(spec.degree)]
             # self row stays the FRESH full-precision buffer; only the
             # gathered neighbor rows go through the codec wire
-            stack = jnp.stack([buf] + srcs, axis=1)  # (B, S+1, rows, 128)
-            out = jnp.einsum("bk,bk...->b...", w_local,
-                             stack.astype(jnp.float32), precision=_EXACT)
-            out_bufs.append(out.astype(buf.dtype))
+            out_bufs.append(_weighted_rows(w_local, buf, srcs))
             if tel is not None and tel.consensus:
-                # residuals off the already-gathered stack: the telemetry
+                # residuals off the already-gathered rows: the telemetry
                 # build ships the exact same permutes as the metrics-off one
-                for s in range(spec.degree):
+                for s, g in enumerate(srcs):
                     resid = resid + tcontrib_local[:, 1 + s] * vsq(
-                        stack[:, 1 + s].astype(jnp.float32)
-                        - buf.astype(jnp.float32))
+                        g.astype(jnp.float32) - buf.astype(jnp.float32))
         if tel is not None and tel.consensus:
             metrics["resid_sqnorm"] = resid
         mixed = jax.vmap(lambda bso: packing.unpack_tree(bso, pack_spec))(

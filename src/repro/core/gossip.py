@@ -439,7 +439,7 @@ def mix_dense_gated(tree: PyTree, spec: GossipSpec,
     row has at most two live contributors** (one-peer rotation: self + one
     sender; the remaining terms are exact zeros and f32 addition is
     commutative). With three or more live contributors the dense row (sender
-    order) and the packed stack (schedule order) sum in different orders and
+    order) and the stacked round (schedule order) sum in different orders and
     may differ in the last ulp — compare with allclose there.
     """
     m = gated_mixing_matrix(spec, gates, alive)
@@ -529,12 +529,10 @@ def mix_packed_stacked(tree: PyTree, spec: GossipSpec,
     """Stacked-axis packed executor — the simulator counterpart of
     :func:`ppermute_mix_packed` and the mixing path of the elastic runtime.
 
-    The client-stacked pytree packs (vmapped) into one ``(n, rows, 128)``
-    flat buffer per dtype, each schedule becomes one gather on the stacked
-    axis, and the weighted reduction runs as a single fused contraction over
-    the ``(n, S+1, rows, 128)`` stack — the XLA analogue of the
-    ``gossip_mix_2d`` kernel pass, with none of the per-leaf flatten work of
-    :func:`mix_schedules`. With ``alive`` (a *traced* ``(n,)`` 0/1 vector)
+    Each schedule becomes one gather on the stacked axis of every leaf, and
+    each leaf's weighted reduction is one f32 multiply-add over its own row
+    and its gathered neighbour rows, with no packed copy of the state and
+    no ``(n, S+1, ...)`` stack. With ``alive`` (a *traced* ``(n,)`` 0/1 vector)
     the reduction uses the renormalized masked weights of
     :func:`alive_weight_table`, so straggler-set changes are plain data and
     never retrace the enclosing jit; ``gates`` (a traced per-schedule float
@@ -589,7 +587,8 @@ def mix_packed_stacked_delayed(tree: PyTree,
     alive/gates weight table as the synchronous path. Returns the mixed tree
     and the new snapshot (this round's packed fresh state), to be carried as
     step state. With ``snapshot == pack_state_stacked(tree)`` the result is
-    bit-identical to :func:`mix_packed_stacked` (same stack, same einsum).
+    bit-identical to :func:`mix_packed_stacked` (the same f32 multiply-add
+    over the same rows, on the packed buffers here and on the leaves there).
 
     Engine cell: ``stacked x f32 x delayed`` (:mod:`repro.core.engine`).
     """

@@ -11,6 +11,7 @@ The tentpole claims under test:
   format through splice repair, retraces nothing under churn + active
   plans, and ships exactly d int8 collectives per round in lowered HLO.
 """
+import re
 import subprocess
 import sys
 import textwrap
@@ -168,6 +169,95 @@ class TestLegacyEntryPointsResolveThroughEngine:
             for marker in ("lax.ppermute", "jnp.stack", "jnp.einsum",
                            "quantize_packed", "dequant_accumulate"):
                 assert marker not in src, (fn.__name__, marker)
+
+
+class TestStackedLeafwiseRound:
+    """The stacked f32 sync round gathers and mixes the leaves where they
+    are: an f32 multiply-add over each leaf's gathered neighbour rows, with
+    no packed buffer and no (n, d+1, ...) stack."""
+
+    @staticmethod
+    def _case(n, d, masked, seed=0):
+        spec = gossip.make_gossip_spec(topology.expander_overlay(n, d,
+                                                                 seed=seed))
+        if not masked:
+            return spec, {}
+        r = np.random.default_rng(seed + n + d)
+        alive = (r.random(n) > 0.3).astype(np.float32)
+        alive[:2] = 1.0
+        gates = np.ones(spec.degree, np.float32)
+        gates[r.integers(spec.degree)] = 0.0
+        return spec, {"alive": jnp.asarray(alive),
+                      "gates": jnp.asarray(gates)}
+
+    @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+    @pytest.mark.parametrize("masked", [False, True])
+    @pytest.mark.parametrize("d", [2, 4])
+    @pytest.mark.parametrize("n", [8, 12, 32])
+    def test_matches_dense_gated_oracle(self, n, d, masked, dtype):
+        spec, kw = self._case(n, d, masked)
+        x = jax.tree.map(lambda v: v.astype(dtype), _tree(n, seed=n + d))
+        ex = engine.build_gossip_executor(
+            engine.GossipEngineConfig(substrate="stacked"), spec)
+        got = jax.jit(lambda t, **k: ex(t, **k))(x, **kw)
+        ref = gossip.mix_dense_gated(x, spec, kw.get("gates"),
+                                     kw.get("alive"))
+        # the oracle sums over senders, the round in schedule order: f32
+        # agrees to a few ulp; bf16 to one ulp of the final rounding
+        tol = 2e-5 if dtype == "float32" else 1e-2
+        for k in x:
+            assert got[k].dtype == x[k].dtype
+            np.testing.assert_allclose(
+                np.asarray(got[k], np.float32), np.asarray(ref[k], np.float32),
+                rtol=tol, atol=tol)
+
+    @staticmethod
+    def _out_shapes(jaxpr):
+        """Every equation's output shape, nested jaxprs included."""
+        out = []
+        for eqn in jaxpr.eqns:
+            out.extend(tuple(v.aval.shape) for v in eqn.outvars)
+            for p in eqn.params.values():
+                for sub in (p if isinstance(p, (tuple, list)) else (p,)):
+                    inner = getattr(sub, "jaxpr", sub)
+                    if hasattr(inner, "eqns"):
+                        out.extend(TestStackedLeafwiseRound._out_shapes(
+                            inner))
+        return out
+
+    @pytest.mark.parametrize("masked", [False, True])
+    def test_builds_no_stack_and_no_packed_state(self, masked):
+        """Structural guard on the traced round: no array of shape
+        (n, d+1, ...) and no (n, rows, 128) pack buffer of the state. The
+        delayed round, which carries the packed snapshot, shows the guard
+        sees such a buffer where one is built."""
+        n, d = 12, 4
+        spec, kw = self._case(n, d, masked)
+        x = _tree(n)
+
+        def shapes(delay):
+            ex = engine.build_gossip_executor(
+                engine.GossipEngineConfig(substrate="stacked", delay=delay),
+                spec)
+            if delay:
+                fn = lambda t, s, **k: ex(t, state=s, **k)  # noqa: E731
+                args = (x, ex.init_state(x))
+            else:
+                fn, args = (lambda t, **k: ex(t, **k)), (x,)
+            return self._out_shapes(jax.make_jaxpr(fn)(*args, **kw).jaxpr)
+
+        def packed(s):
+            return len(s) == 3 and s[0] == n and s[2] == packing.LANE
+
+        sync = shapes(0)
+        assert not [s for s in sync if len(s) > 2 and s[:2] == (n, d + 1)]
+        assert not [s for s in sync if packed(s)]
+        assert [s for s in shapes(1) if packed(s)]
+        text = jax.jit(lambda t, **k: engine.build_gossip_executor(
+            engine.GossipEngineConfig(substrate="stacked"), spec)(t, **k)
+        ).lower(x, **kw).as_text(debug_info=True)
+        assert "dfl.gossip/mix/" in text
+        assert not re.search(r'dfl\.gossip/[^"]*pack', text)
 
 
 class TestStackedQuantCells:

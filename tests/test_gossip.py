@@ -201,8 +201,8 @@ class TestDelayedGossip:
 
     def test_self_snapshot_is_bitwise_sync(self):
         """delay=0 anchor: feeding the CURRENT tree as the snapshot must
-        reproduce the synchronous packed executor bit-for-bit (identical
-        stack, identical einsum)."""
+        reproduce the synchronous stacked executor bit-for-bit (the same
+        f32 multiply-add over the same rows)."""
         ov = topology.expander_overlay(8, 4, seed=1)
         spec = gossip.make_gossip_spec(ov)
         x = _tree(8, seed=9)

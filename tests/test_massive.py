@@ -200,7 +200,8 @@ def _island(executor, mesh):
 
 class TestBlockedParityOneDevice:
     """block = n on the single local device: the blocked round must be
-    BITWISE identical to the stacked round (identical stack + einsum)."""
+    BITWISE identical to the stacked round (the same f32 multiply-add over
+    the same rows)."""
 
     def test_bitwise_vs_stacked_with_alive_and_gates(self):
         from jax.sharding import Mesh

@@ -711,26 +711,42 @@ class TestScopesAndSpans:
     def test_round_fn_carries_the_layer_scopes(self):
         """The compiled round names its layers in every instruction's
         op_name: the local phase's while loop under dfl.local, the mix
-        under dfl.gossip, the packing copies under dfl.gossip/pack."""
+        under dfl.gossip/mix, and the packing copies under dfl.gossip/pack
+        in a round whose wire needs the packed layout. The f32 round mixes
+        the leaves where they are: it enters no pack or unpack scope."""
         from repro.launch.train import SimTrainer
         n = 8
-        tr = SimTrainer(overlay=topology.expander_overlay(n, 4, seed=0),
-                        loss_fn=_scoped_loss,
-                        dcfg=dfedavg.DFedAvgMConfig(local_steps=2, lr=0.3,
-                                                    momentum=0.5))
-        params = {"w": jnp.zeros((n, 5)), "b": jnp.ones((n, 3))}
-        text = tr.round_fn.lower(
-            params, {"t": jnp.ones((n, 2, 5))}, jnp.float32(0.1),
-            jnp.ones(n, jnp.float32), tr._gates(0), None,
-            None).compile().as_text()
-        ops = dict(re.findall(
-            r'^\s*(?:ROOT )?%([\w.-]+) = .*op_name="([^"]*)"', text, re.M))
-        whiles = [v for k, v in ops.items() if k.startswith("while")]
-        assert whiles and all("dfl.local" in v for v in whiles)
-        assert any(v.endswith("dfl.gossip/mul") for v in ops.values())
-        assert any("dfl.gossip/vmap(pack)/" in v for v in ops.values())
-        assert any("dfl.gossip/vmap(unpack)/" in v for v in ops.values())
-        assert not any("dfl.gossip" in v for v in whiles)
+
+        def compiled_ops(codec):
+            tr = SimTrainer(overlay=topology.expander_overlay(n, 4, seed=0),
+                            loss_fn=_scoped_loss,
+                            dcfg=dfedavg.DFedAvgMConfig(local_steps=2,
+                                                        lr=0.3, momentum=0.5),
+                            engine=engine.GossipEngineConfig(
+                                substrate="stacked", codec=codec))
+            params = {"w": jnp.zeros((n, 5)), "b": jnp.ones((n, 3))}
+            text = tr.round_fn.lower(
+                params, {"t": jnp.ones((n, 2, 5))}, jnp.float32(0.1),
+                jnp.ones(n, jnp.float32), tr._gates(0), None,
+                None).compile().as_text()
+            return dict(re.findall(
+                r'^\s*(?:ROOT )?%([\w.-]+) = .*op_name="([^"]*)"', text,
+                re.M))
+
+        for codec in ("f32", "int8_block"):
+            ops = compiled_ops(codec)
+            whiles = [v for k, v in ops.items() if k.startswith("while")]
+            assert whiles and all("dfl.local" in v for v in whiles)
+            assert not any("dfl.gossip" in v for v in whiles)
+            assert any("dfl.gossip/mix/" in v for v in ops.values()), codec
+            packed = [v for v in ops.values()
+                      if "dfl.gossip/vmap(pack)/" in v
+                      or "dfl.gossip/vmap(unpack)/" in v]
+            if codec == "f32":
+                assert not packed
+            else:
+                assert any("/vmap(pack)/" in v for v in packed)
+                assert any("/vmap(unpack)/" in v for v in packed)
 
     def test_elastic_round_spans(self, tmp_path):
         """Each ElasticTrainer.step is a dfl.round step span (step number =
