@@ -7,11 +7,11 @@ death with splice repair — and reports:
   * rounds/sec per phase (healthy vs straggler-churn vs post-repair);
   * the jit trace count (`n_traces`): straggler churn must add ZERO traces
     (the alive mask is a step argument); each membership change adds exactly
-    one. The same guard runs for the **pipelined** trainer (gossip_delay=1):
+    one. The same guard runs for the **pipelined** trainer (engine delay=1):
     the in-flight snapshot is step state, never trace structure;
   * a delayed-vs-sync convergence proxy: final mean-square distance to the
-    shared quadratic target after the same scripted churn, gossip_delay=0 vs
-    1 — one-round staleness costs a bounded constant, not divergence.
+    shared quadratic target after the same scripted churn, engine delay 0
+    vs 1 — one-round staleness costs a bounded constant, not divergence.
 
 Output: the usual ``name,us_per_call,derived`` CSV rows, plus one JSON
 record written to ``<out>/elastic.json`` (default ``experiments/bench/``;
@@ -125,11 +125,11 @@ def run(n_clients: int = 16, degree: int = 4, dim: int = 4096,
 
 def run_delayed(n_clients: int = 16, degree: int = 4, dim: int = 4096,
                 rounds: int = 16, seed: int = 0) -> dict:
-    """Pipelined (gossip_delay=1) vs synchronous trainer under identical
+    """Pipelined (engine delay=1) vs synchronous trainer under identical
     straggler churn: retrace guard + convergence proxy + rounds/sec.
 
     The third line is the **pipelined + quantized** engine composition
-    (gossip_codec="int8_block", delay=1): same churn, int8 wire snapshot —
+    (codec="int8_block", delay=1): same churn, int8 wire snapshot —
     its retrace count must also stay 1 and its convergence proxy must land
     in the same neighborhood as the f32 pipeline (the int8 error is bounded
     by the per-tile scales, not compounding)."""

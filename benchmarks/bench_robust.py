@@ -129,7 +129,7 @@ def _screen_overhead(n, degree, dim, *, trim, seed=0):
         alive = jnp.ones(n, jnp.float32)
         gates = trainer.gates_for_round(0)
         lr = jnp.asarray(0.2, jnp.float32)
-        out[screen] = time_call(trainer._round, params, _batches(n, dim),
+        out[screen] = time_call(trainer._round_fn, params, _batches(n, dim),
                                 lr, alive, gates, None, None, iters=10)
     return out
 

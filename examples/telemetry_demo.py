@@ -1,9 +1,9 @@
 """Telemetry walkthrough: the same elastic run, fully observable.
 
-Act 1 — in-graph round metrics: switch ``telemetry=TelemetryConfig()`` on
-an ElasticTrainer and every round also returns traced scalars computed
-INSIDE the jitted round — the consensus residual (how far the clients
-disagree), the realized in-degree under churn, the per-schedule gate
+Act 1 — in-graph round metrics: set ``telemetry=TelemetryConfig()`` on an
+ElasticTrainer's engine config and every round also returns traced scalars
+computed INSIDE the jitted round — the consensus residual (how far the
+clients disagree), the realized in-degree under churn, the per-schedule gate
 mass.  No extra collectives, no retraces: off, the round lowers to
 bit-identical HLO; on, the metrics ride values the mix already holds.
 
@@ -48,7 +48,9 @@ print("== act 1: in-graph round metrics (no logger, no host syncs) ==")
 trainer = ElasticTrainer(
     overlay=expander_overlay(N, DEGREE, seed=0), loss_fn=loss_fn,
     dcfg=dfedavg.DFedAvgMConfig(local_steps=2, lr=0.2, momentum=0.5),
-    failure_rounds=10**9, telemetry=TelemetryConfig())
+    failure_rounds=10**9,
+    engine=engine_lib.GossipEngineConfig(substrate="stacked",
+                                         telemetry=TelemetryConfig()))
 params = init
 print("round  resid_sqnorm  in_degree(mean)  live")
 for rnd in range(6):

@@ -99,7 +99,6 @@ __all__ = [
     "get_codec",
     "parse_gossip_impl",
     "register_codec",
-    "resolve_trainer_engine",
 ]
 
 PyTree = Any
@@ -333,79 +332,6 @@ def parse_gossip_impl(gossip_impl: str, delay: int = 0,
                               sub_rounds=sub_rounds, screen=screen,
                               clip_tau=clip_tau, trim_f=trim_f,
                               telemetry=telemetry)
-
-
-# legacy per-knob trainer arguments and their defaults — the shim behind the
-# trainers' ``engine=GossipEngineConfig(...)`` front door. NOTE the naming
-# drift this resolves: the trainers historically called the norm-clip
-# threshold ``screen_tau`` while ParallelConfig calls it ``gossip_clip_tau``;
-# both are GossipEngineConfig.clip_tau.
-_LEGACY_TRAINER_KNOBS = (
-    ("gossip_codec", "f32"),
-    ("gossip_delay", 0),
-    ("gossip_sub_rounds", 1),
-    ("gossip_block", 0),
-    ("gossip_screen", "none"),
-    ("screen_tau", 3.0),
-    ("screen_trim", 1),
-)
-
-
-def resolve_trainer_engine(trainer) -> None:
-    """ONE engine-config front door for the simulator trainers.
-
-    ``trainer`` is an ElasticTrainer / SimTrainer mid-``__post_init__``: if
-    ``trainer.engine`` is a :class:`GossipEngineConfig`, its cell is mirrored
-    onto the legacy per-knob attributes (everything downstream — round
-    builders, splice repair, the step — keeps reading one source of truth),
-    so ``engine=`` construction is bitwise-equivalent to the knobs it
-    replaces. Passing both is an error; passing non-default legacy knobs
-    without ``engine=`` emits a :class:`DeprecationWarning` naming the
-    replacement.
-    """
-    explicit = [k for k, d in _LEGACY_TRAINER_KNOBS
-                if getattr(trainer, k) != d]
-    if trainer.engine is not None:
-        if explicit:
-            raise ValueError(
-                "pass the engine cell EITHER as engine=GossipEngineConfig("
-                "...) or via the legacy gossip_* knobs, not both (legacy "
-                f"knobs set: {', '.join(explicit)})")
-        ecfg = trainer.engine
-        if not isinstance(ecfg, GossipEngineConfig):
-            raise TypeError("engine must be a repro.core.engine."
-                            "GossipEngineConfig (got "
-                            f"{type(ecfg).__name__})")
-        if ecfg.substrate not in ("stacked", "blocked"):
-            raise ValueError(
-                f"{type(trainer).__name__} runs the stacked | blocked "
-                f"substrates, got engine.substrate={ecfg.substrate!r} "
-                "(production shard_map cells are built by "
-                "launch.steps.build_train_step from ParallelConfig)")
-        trainer.gossip_codec = ecfg.codec
-        trainer.gossip_delay = ecfg.delay
-        trainer.gossip_sub_rounds = ecfg.sub_rounds
-        trainer.gossip_screen = ecfg.screen
-        trainer.screen_tau = ecfg.clip_tau
-        trainer.screen_trim = ecfg.trim_f
-        trainer.gossip_block = ecfg.block if ecfg.substrate == "blocked" else 0
-        if ecfg.telemetry is not None:
-            if trainer.telemetry is not None:
-                raise ValueError("telemetry passed twice: on the engine "
-                                 "config AND the trainer; set it in one "
-                                 "place")
-            trainer.telemetry = ecfg.telemetry
-    elif explicit:
-        import warnings
-        warnings.warn(
-            f"the per-knob gossip arguments ({', '.join(explicit)}) of "
-            f"{type(trainer).__name__} are deprecated; pass engine="
-            "repro.core.engine.GossipEngineConfig(substrate='stacked' | "
-            "'blocked', codec=..., delay=..., screen=..., clip_tau=..., "
-            "trim_f=..., block=...) instead (the trainer knob screen_tau "
-            "is GossipEngineConfig.clip_tau — the value ParallelConfig "
-            "calls gossip_clip_tau)",
-            DeprecationWarning, stacklevel=4)
 
 
 # ------------------------------------------------------------------ codecs

@@ -312,8 +312,8 @@ def build_train_step(cfg: ModelConfig, shape: ShapeConfig, base_mesh: Mesh,
     # would clamp those rows to the lazy variant and silently change
     # numerics (the gates argument is still accepted and simply unused).
     # The name is validated so a typo errors instead of silently flipping
-    # the gate semantics; this rule must agree with plan_lib.is_active
-    # (see launch/elastic.py's StepBuilder note).
+    # the gate semantics; this rule must agree with plan_lib.is_active,
+    # the predicate the simulator trainers pass to launch.sim_round.
     from repro.overlay import plan as plan_lib
     if dfl.round_plan not in plan_lib.PLAN_NAMES:
         raise ValueError(f"unknown round_plan {dfl.round_plan!r}; "

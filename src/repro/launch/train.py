@@ -15,7 +15,6 @@ from __future__ import annotations
 import argparse
 import dataclasses
 import json
-from functools import partial
 from typing import Any, Callable
 
 import jax
@@ -27,7 +26,7 @@ from repro.configs.base import DFLConfig
 from repro.core import dfedavg, engine as engine_lib, failures as failures_lib, \
     gossip as gossip_lib
 from repro.core.topology import Overlay
-from repro.launch import compile_cache
+from repro.launch import compile_cache, sim_round
 from repro.launch.steps import build_overlay
 from repro.models import lstm as lstm_model
 from repro.models import params as params_lib
@@ -46,80 +45,30 @@ class SimTrainer:
     loss_fn: Callable
     dcfg: dfedavg.DFedAvgMConfig
     ckpt: CheckpointManager | None = None
-    # THE engine front door: the whole gossip cell as one
-    # repro.core.engine.GossipEngineConfig (substrate "stacked" or
-    # "blocked" + codec x delay x screen x telemetry). The per-knob
-    # gossip_* arguments below are a deprecated shim that mirrors into the
-    # same config (engine_lib.resolve_trainer_engine) — either spelling
-    # builds the bitwise-identical round.
-    engine: engine_lib.GossipEngineConfig | None = None
+    # the gossip cell: substrate "stacked" or "blocked" (block=B clients
+    # per device, n/B devices) x codec x delay x sub_rounds x screen x
+    # telemetry. With engine.telemetry set, run()'s history records carry
+    # the round metrics' host summary (consensus residual, in-degree, gate
+    # mass, clip counts).
+    engine: engine_lib.GossipEngineConfig = engine_lib.GossipEngineConfig(
+        substrate="stacked")
     plan: overlay_plan.RoundPlan | None = None  # time-varying gates source
     # round-level client subsampling (active-set plans): the 0/1
     # participation vector multiplies the alive mask each round — inactive
     # clients keep their params (identity rows); cohort rotation is data,
     # never a retrace. None (or the "full" plan) = everyone participates.
     active_plan: overlay_plan.ActiveSetPlan | None = None
-    # B > 0 = blocked substrate (massive-client simulation): n/B devices
-    # each hold a (B, ...) stacked client slice; cross-device schedule
-    # parts ship as whole-block ppermutes (repro.core.gossip.BlockedSpec).
-    # 0 = single-device stacked round (unchanged path).
-    gossip_block: int = 0
-    # 1 = pipelined gossip (mix the previous round's packed snapshot,
-    # mix_dense_delayed semantics); 0 = synchronous (unchanged)
-    gossip_delay: int = 0
-    # k >= 2 = Chebyshev multi-round gossip (engine sub_rounds axis): k
-    # gossip sub-rounds per round with Chebyshev polynomial weights over
-    # the mixing matrix, coefficients shipped as traced data from
-    # executor.cheby_coeffs() — zero retraces, refreshed after repairs.
-    # 1 = the sync engine round, bit-identical (unchanged path).
-    gossip_sub_rounds: int = 1
-    # wire codec of the stacked engine round ("f32" | "int8" | "int8_block")
-    gossip_codec: str = "f32"
-    # Byzantine screen ("none" | "norm_clip" | "trimmed_mean") + its knobs;
-    # composes with every codec x delay cell through the engine config alone
-    gossip_screen: str = "none"
-    screen_tau: float = 3.0
-    screen_trim: int = 1
     # scripted attackers: the (2, n) round_vector + PRNG key are traced
     # data, so attacker churn never retraces the round
     attack_plan: failures_lib.AttackPlan | None = None
     attack_seed: int = 0
-    # opt-in in-graph round metrics (repro.telemetry.TelemetryConfig):
-    # when set, the stacked engine round additionally returns a traced
-    # RoundMetrics dict and run()'s history records carry its host summary
-    # (consensus residual, in-degree, gate mass, clip counts). None (the
-    # default) lowers the round exactly as before.
-    telemetry: telemetry_metrics.TelemetryConfig | None = None
     # optional structured JSONL event stream (round records, compiles,
     # repairs) — see repro.telemetry.TelemetryLogger
     logger: TelemetryLogger | None = None
 
     def __post_init__(self):
-        # engine= front door first: mirrors the config onto the legacy
-        # knobs (or warns on deprecated per-knob use), so every check and
-        # builder below reads one source of truth
-        engine_lib.resolve_trainer_engine(self)
-        if self.gossip_delay not in (0, 1):
-            raise ValueError(f"gossip_delay must be 0 or 1, "
-                             f"got {self.gossip_delay}")
-        if self.gossip_screen not in engine_lib.SCREENS:
-            raise ValueError(f"unknown gossip_screen {self.gossip_screen!r}; "
-                             f"available: {', '.join(engine_lib.SCREENS)}")
-        if (self.attack_plan is not None
-                and self.attack_plan.n_clients != self.overlay.n):
-            raise ValueError(f"attack_plan is for "
-                             f"{self.attack_plan.n_clients} clients, overlay "
-                             f"has {self.overlay.n}")
-        if self.gossip_block:
-            if self.gossip_block < 0 or self.overlay.n % self.gossip_block:
-                raise ValueError(
-                    f"gossip_block={self.gossip_block} must be a positive "
-                    f"divisor of the client count {self.overlay.n}")
-            if self.overlay.n // self.gossip_block > len(jax.devices()):
-                raise ValueError(
-                    f"blocked layout needs "
-                    f"{self.overlay.n // self.gossip_block} devices "
-                    f"(= n/block), only {len(jax.devices())} visible")
+        sim_round.check("SimTrainer", self.engine, self.overlay.n,
+                        self.attack_plan)
         self.spec = gossip_lib.make_gossip_spec(self.overlay)
         # shared retrace accounting (emits "compile" events when logging)
         self.tracer = TraceCounter("sim_round", logger=self.logger)
@@ -134,158 +83,12 @@ class SimTrainer:
         self._round_fn = self._build(self.spec)
 
     def _build(self, spec):
-        # no active plan (None or static) => gate pathway off at build time
-        # (exact Chow weights; shared predicate with ElasticTrainer/steps.py)
-        use_plan = overlay_plan.is_active(self.plan)
-        use_attack = self.attack_plan is not None
-        use_tel = self.telemetry is not None
-
-        def client(p, b, lr):
-            v = jax.tree.map(jnp.zeros_like, p)
-            p, _, loss = dfedavg.local_round(p, v, b, self.loss_fn,
-                                             self.dcfg, lr=lr)
-            return p, loss
-
-        if self.gossip_block:
-            # blocked substrate: shard_map gossip island over a 1-D
-            # client-device mesh; the local phase runs on the GSPMD-sharded
-            # full stack (see launch/elastic.py for the full design note)
-            from jax.sharding import Mesh, PartitionSpec as P
-            from repro.launch import mesh as mesh_lib
-            b_sz = self.gossip_block
-            mesh = Mesh(np.asarray(jax.devices()[:spec.n_clients // b_sz]),
-                        ("clients",))
-            self._gossip_mesh = mesh  # repair re-places state onto this
-            self._executor = engine_lib.build_gossip_executor(
-                engine_lib.GossipEngineConfig(
-                    substrate="blocked", codec=self.gossip_codec,
-                    delay=self.gossip_delay,
-                    sub_rounds=self.gossip_sub_rounds,
-                    screen=self.gossip_screen,
-                    clip_tau=self.screen_tau, trim_f=self.screen_trim,
-                    block=b_sz, telemetry=self.telemetry),
-                spec, axis_names="clients")
-            executor = self._executor
-
-            @partial(jax.jit, static_argnames=())
-            def round_fn(params, batches, lr, alive, gates, attack, akey):
-                self.tracer.hit()  # python side effect: runs only on trace
-                params, losses = jax.vmap(client, in_axes=(0, 0, None))(
-                    params, batches, lr)
-                if use_attack:
-                    params = failures_lib.apply_attack(params, attack, akey)
-
-                def island(p, alive_vec, gate_vec):
-                    return executor(p, alive=alive_vec,
-                                    gates=gate_vec if use_plan else None)
-
-                # blocked telemetry: the island returns device-local
-                # (block,)-leading metric rows; the P("clients") out_spec
-                # concatenates them back to the (n,)-stacked layout with
-                # zero extra collectives
-                if use_tel:
-                    params, metrics = mesh_lib.shard_map(
-                        island, mesh, in_specs=(P("clients"), P(), P()),
-                        out_specs=(P("clients"), P("clients")))(
-                        params, alive, gates)
-                else:
-                    params = mesh_lib.shard_map(
-                        island, mesh, in_specs=(P("clients"), P(), P()),
-                        out_specs=P("clients"))(params, alive, gates)
-                    metrics = None
-                return params, losses, metrics
-            return round_fn
-
-        self._executor = engine_lib.build_gossip_executor(
-            engine_lib.GossipEngineConfig(substrate="stacked",
-                                          codec=self.gossip_codec,
-                                          delay=self.gossip_delay,
-                                          sub_rounds=self.gossip_sub_rounds,
-                                          screen=self.gossip_screen,
-                                          clip_tau=self.screen_tau,
-                                          trim_f=self.screen_trim,
-                                          telemetry=self.telemetry), spec)
-        executor = self._executor
-
-        if self.gossip_sub_rounds > 1:
-            # Chebyshev multi-round round: the (k,) coefficient vector is
-            # one more traced data argument (the engine config has already
-            # rejected delay / screens / stateful codecs for this cell)
-            @partial(jax.jit, static_argnames=())
-            def round_fn(params, batches, lr, alive, gates, attack, akey,
-                         cheby):
-                self.tracer.hit()  # python side effect: runs only on trace
-                params, losses = jax.vmap(client, in_axes=(0, 0, None))(
-                    params, batches, lr)
-                if use_attack:
-                    params = failures_lib.apply_attack(params, attack, akey)
-                out = executor(params, alive=alive,
-                               gates=gates if use_plan else None,
-                               cheby=cheby)
-                if use_tel:
-                    params, metrics = out
-                else:
-                    params, metrics = out, None
-                return params, losses, metrics
-            return round_fn
-
-        if executor.stateful:
-            # stateful codec (topk_ef): the per-client codec state rides as
-            # a second threaded state channel next to the optional delay
-            # snapshot (inflight stays None — an empty pytree — at delay=0)
-            @partial(jax.jit, static_argnames=())
-            def round_fn(params, inflight, cstate, batches, lr, alive,
-                         gates, attack, akey):
-                self.tracer.hit()  # python side effect: only runs on trace
-                params, losses = jax.vmap(client, in_axes=(0, 0, None))(
-                    params, batches, lr)
-                if use_attack:
-                    params = failures_lib.apply_attack(params, attack, akey)
-                kw = dict(codec_state=cstate, alive=alive,
-                          gates=gates if use_plan else None)
-                if self.gossip_delay:
-                    kw["state"] = inflight
-                out = list(executor(params, **kw))
-                mixed = out.pop(0)
-                inflight = out.pop(0) if self.gossip_delay else None
-                cstate = out.pop(0)
-                metrics = out.pop(0) if use_tel else None
-                return mixed, losses, inflight, cstate, metrics
-            return round_fn
-
-        if self.gossip_delay:
-            @partial(jax.jit, static_argnames=())
-            def round_fn(params, inflight, batches, lr, alive, gates,
-                         attack, akey):
-                self.tracer.hit()  # python side effect: only runs on trace
-                params, losses = jax.vmap(client, in_axes=(0, 0, None))(
-                    params, batches, lr)
-                if use_attack:
-                    params = failures_lib.apply_attack(params, attack, akey)
-                out = executor(params, state=inflight, alive=alive,
-                               gates=gates if use_plan else None)
-                if use_tel:
-                    params, inflight, metrics = out
-                else:
-                    (params, inflight), metrics = out, None
-                return params, losses, inflight, metrics
-            return round_fn
-
-        @partial(jax.jit, static_argnames=())
-        def round_fn(params, batches, lr, alive, gates, attack, akey):
-            self.tracer.hit()  # python side effect: runs only when tracing
-            params, losses = jax.vmap(client, in_axes=(0, 0, None))(
-                params, batches, lr)
-            if use_attack:
-                params = failures_lib.apply_attack(params, attack, akey)
-            out = executor(params, alive=alive,
-                           gates=gates if use_plan else None)
-            if use_tel:
-                params, metrics = out
-            else:
-                params, metrics = out, None
-            return params, losses, metrics
-        return round_fn
+        """The shared simulator round (launch.sim_round) for ``spec``."""
+        self._sim = sim_round.build(
+            self.engine, spec, loss_fn=self.loss_fn, dcfg=self.dcfg,
+            use_plan=overlay_plan.is_active(self.plan),
+            use_attack=self.attack_plan is not None, tracer=self.tracer)
+        return self._sim.round_fn
 
     @property
     def round_fn(self):
@@ -315,14 +118,14 @@ class SimTrainer:
     def repair(self, dead: list[int], params: PyTree) -> PyTree:
         """Permanent failures: splice repair, state remap, re-jit. The
         delayed-mode in-flight snapshot rides the same row compaction."""
-        if self.gossip_block and \
-                (self.overlay.n - len(dead)) % self.gossip_block:
+        block = self.engine.block
+        if block and (self.overlay.n - len(dead)) % block:
             # the blocked layout needs the survivor count to stay a
             # multiple of block; mask the dead via set_stragglers instead
             # (ElasticTrainer automates this masking-vs-splice decision)
             raise ValueError(
                 f"splicing {len(dead)} of {self.overlay.n} clients leaves a "
-                f"partial device block (block={self.gossip_block}); keep the "
+                f"partial device block (block={block}); keep the "
                 "dead masked or evict a block-multiple")
         bundle = (params, self._inflight, self._codec_state)
         self.overlay, self.spec, bundle, old2new = failures_lib.repair_and_remap(
@@ -340,13 +143,7 @@ class SimTrainer:
                                 "spliced": True,
                                 "n_after": self.overlay.n})
         self._round_fn = self._build(self.spec)
-        if self.gossip_block:
-            # a splice can shrink the blocked mesh; the remapped rows are
-            # still committed to the old device set — re-place them
-            from jax.sharding import NamedSharding, PartitionSpec as P
-            params = jax.device_put(
-                params, NamedSharding(self._gossip_mesh, P("clients")))
-        return params
+        return self._sim.place(params)
 
     # ------------------------------------------------------------- train
     def _run_round(self, params, batch_fn, rnd, lr_fn, log_every, eval_fn,
@@ -370,37 +167,11 @@ class SimTrainer:
                     self.active_plan, rnd, self.overlay.n)
             alive_t = jnp.asarray(alive_t)
             gates = self._gates(rnd)
-            cheby = None
-            if (not self._executor.stateful and not self.gossip_delay
-                    and not self.gossip_block and self.gossip_sub_rounds > 1):
-                # coefficients recomputed from the live executor: a repair
-                # rebuilt it with the new spec's lambda, and the fixed (k,)
-                # shape means the refresh never retraces
-                cheby = jnp.asarray(self._executor.cheby_coeffs())
         with span("dfl.dispatch"):
-            if self._executor.stateful:
-                if self._codec_state is None:  # prime: EF residual zeros
-                    self._codec_state = self._executor.init_codec_state(
-                        params)
-                if self.gossip_delay and self._inflight is None:
-                    self._inflight = self._executor.init_state(params)
-                (params, losses, self._inflight, self._codec_state,
-                 metrics) = self._round_fn(
-                    params, self._inflight, self._codec_state, batches,
-                    lr_t, alive_t, gates, attack, akey)
-            elif self.gossip_delay:
-                if self._inflight is None:  # prime with the initial params
-                    self._inflight = self._executor.init_state(params)
-                params, losses, self._inflight, metrics = self._round_fn(
-                    params, self._inflight, batches, lr_t, alive_t, gates,
-                    attack, akey)
-            elif cheby is not None:
-                params, losses, metrics = self._round_fn(
-                    params, batches, lr_t, alive_t, gates, attack, akey,
-                    cheby)
-            else:
-                params, losses, metrics = self._round_fn(
-                    params, batches, lr_t, alive_t, gates, attack, akey)
+            extra = self._sim.extra(params, self._inflight, self._codec_state)
+            params, losses, metrics, *carried = self._round_fn(
+                params, batches, lr_t, alive_t, gates, attack, akey, *extra)
+            self._inflight, self._codec_state = self._sim.carried(carried)
         with span("dfl.sync"):
             loss = float(jnp.mean(losses))
         with span("dfl.record"):
@@ -424,13 +195,7 @@ class SimTrainer:
             failure_plan: failures_lib.FailurePlan | None = None
             ) -> tuple[PyTree, list[dict]]:
         history: list[dict] = []
-        if self.gossip_block:
-            # the blocked round returns params committed to the client
-            # mesh; committing round 0's input there too keeps every round
-            # on one trace (the sharding is part of the traced type)
-            from jax.sharding import NamedSharding, PartitionSpec as P
-            params = jax.device_put(
-                params, NamedSharding(self._gossip_mesh, P("clients")))
+        params = self._sim.place(params)
         for rnd in range(start_round, rounds):
             with span("dfl.round", step=rnd):
                 params, rec = self._run_round(params, batch_fn, rnd, lr_fn,

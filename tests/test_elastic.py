@@ -8,11 +8,13 @@ owner through the index compaction).
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.checkpoint import CheckpointManager
 from repro.core import dfedavg, engine, failures, gossip
 from repro.core.topology import expander_overlay
 from repro.launch.elastic import ElasticTrainer
+from repro.launch.train import SimTrainer
 
 
 def quad_loss(params, batch):
@@ -501,3 +503,61 @@ def test_suspicion_carried_through_remap():
     np.testing.assert_array_equal(remapped.suspicion,
                                   [0, 0, 0, 0, 2, 0, 1])
     assert list(remapped.suspects()) == []
+
+
+# ----------------------------------------- one simulator round, two trainers
+def _two_leaf_loss(params, batch):
+    loss = (jnp.mean(jnp.square(params["w"] - batch["target"]))
+            + jnp.mean(jnp.square(params["b"])))
+    return loss, {}
+
+
+PARITY_CELLS = {
+    "stacked_f32": {},
+    "int8_block_delay1": dict(codec="int8_block", delay=1),
+    "topk_ef": dict(codec="topk_ef"),
+    "topk_ef_delay1": dict(codec="topk_ef", delay=1),
+    "sub_rounds2": dict(sub_rounds=2),
+    "trimmed_mean": dict(screen="trimmed_mean"),
+    "blocked8": dict(substrate="blocked", block=8),
+}
+
+
+@pytest.mark.parametrize("cell", list(PARITY_CELLS))
+def test_sim_and_elastic_trainers_run_the_same_round(cell):
+    """SimTrainer.run and ElasticTrainer.step, given one engine cell, the
+    same params and the same batches, agree bitwise after three rounds,
+    each on one trace."""
+    n, dim = 8, 32
+    cfg = engine.GossipEngineConfig(**{"substrate": "stacked",
+                                       **PARITY_CELLS[cell]})
+    r = np.random.default_rng(4)
+    p0 = {"w": jnp.asarray(r.standard_normal((n, dim)), jnp.float32),
+          "b": jnp.asarray(r.standard_normal((n, 3)), jnp.float32)}
+    batches = _batches(jnp.asarray(r.standard_normal((n, dim)),
+                                   jnp.float32), 2)
+
+    def make(cls):
+        return cls(overlay=expander_overlay(n, 4, seed=0),
+                   loss_fn=_two_leaf_loss,
+                   dcfg=dfedavg.DFedAvgMConfig(local_steps=2, lr=0.2,
+                                               momentum=0.5),
+                   engine=cfg)
+
+    sim, el = make(SimTrainer), make(ElasticTrainer)
+    p_sim, _ = sim.run(p0, lambda rnd: batches, 3, lambda rnd: 0.2)
+    p_el = p0
+    for _ in range(3):
+        p_el, _ = el.step(p_el, batches, 0.2)
+    for k in p0:
+        np.testing.assert_array_equal(np.asarray(p_sim[k]),
+                                      np.asarray(p_el[k]))
+    assert sim.tracer.count == 1 and el.n_traces == 1
+
+
+@pytest.mark.parametrize("cls", [SimTrainer, ElasticTrainer])
+def test_trainers_reject_a_shard_map_engine(cls):
+    with pytest.raises(ValueError, match=r"runs the stacked \| blocked"):
+        cls(overlay=expander_overlay(8, 4, seed=0), loss_fn=quad_loss,
+            dcfg=dfedavg.DFedAvgMConfig(local_steps=1, lr=0.2, momentum=0.0),
+            engine=engine.GossipEngineConfig(substrate="shard_map"))

@@ -16,7 +16,6 @@ Covers the PR's acceptance criteria:
 import subprocess
 import sys
 import textwrap
-import warnings
 
 import jax
 import jax.numpy as jnp
@@ -118,35 +117,21 @@ class TestCodecRegistry:
 
 class TestEngineFrontDoor:
     def test_engine_config_bitwise_equals_legacy_default(self):
-        """engine=stacked/f32 and the legacy default knobs drive the exact
-        same round: params agree bitwise after three rounds."""
+        """The default engine and an explicit stacked/f32 engine drive the
+        exact same round: params agree bitwise after three rounds."""
         n, dim = 8, 64
         r = np.random.default_rng(1)
         p0 = {"w": jnp.asarray(r.standard_normal((n, dim)), jnp.float32)}
         targets = jnp.asarray(r.standard_normal((n, dim)), jnp.float32)
-        with warnings.catch_warnings():
-            warnings.simplefilter("error")  # defaults must NOT warn
-            legacy = _trainer(n)
+        default = _trainer(n)
         front = _trainer(n, engine=engine.GossipEngineConfig(
             substrate="stacked", codec="f32"))
         pa = pb = p0
         for _ in range(3):
-            pa, _ = legacy.step(pa, _batches(targets), 0.1)
+            pa, _ = default.step(pa, _batches(targets), 0.1)
             pb, _ = front.step(pb, _batches(targets), 0.1)
         np.testing.assert_array_equal(np.asarray(pa["w"]),
                                       np.asarray(pb["w"]))
-
-    def test_legacy_knobs_warn_and_still_work(self):
-        with warnings.catch_warnings(record=True) as w:
-            warnings.simplefilter("always")
-            trainer = _trainer(6, gossip_codec="int8_block")
-        assert any(issubclass(x.category, DeprecationWarning) for x in w), w
-        assert trainer.gossip_codec == "int8_block"
-
-    def test_engine_plus_legacy_knobs_is_an_error(self):
-        with pytest.raises(ValueError, match="not both"):
-            _trainer(6, gossip_codec="int8",
-                     engine=engine.GossipEngineConfig(substrate="stacked"))
 
 
 class TestCodecStateElastic:

@@ -264,7 +264,8 @@ class TestElasticTelemetry:
             dcfg=dfedavg.DFedAvgMConfig(local_steps=1, lr=0.2, momentum=0.9),
             plan=plan_lib.OnePeerPlan(),
             active_plan=plan_lib.RandomKActiveSet(k=8, seed=0),
-            telemetry=TelemetryConfig())
+            engine=engine.GossipEngineConfig(substrate="stacked",
+                                             telemetry=TelemetryConfig()))
         params = {"w": _tree(n, shapes=((32,),))["p0"]}
         r = np.random.default_rng(0)
         for rnd in range(4):
@@ -292,19 +293,14 @@ class TestElasticTelemetry:
     def test_validation_rejects_unsupported_compositions(self):
         ov = topology.expander_overlay(8, 4, seed=0)
         dcfg = dfedavg.DFedAvgMConfig(local_steps=1, lr=0.2, momentum=0.9)
-        with pytest.raises(ValueError, match="step_builder"):
-            ElasticTrainer(overlay=ov, loss_fn=_quad_loss, dcfg=dcfg,
-                           step_builder=lambda spec, tr: None,
-                           telemetry=TelemetryConfig())
         # blocked + telemetry is now a supported (metrics-only) cell, but
         # Chebyshev sub-rounds still don't ride the blocked substrate
         with pytest.raises(ValueError, match="sub_rounds > 1"):
             ElasticTrainer(overlay=ov, loss_fn=_quad_loss, dcfg=dcfg,
                            engine=engine.GossipEngineConfig(
                                substrate="blocked", block=8, sub_rounds=2))
-        with pytest.raises(TypeError, match="TelemetryConfig"):
-            ElasticTrainer(overlay=ov, loss_fn=_quad_loss, dcfg=dcfg,
-                           telemetry=True)
+        with pytest.raises(ValueError, match="TelemetryConfig"):
+            engine.GossipEngineConfig(substrate="stacked", telemetry=True)
 
 
 # ---------------------------------------------- blocked-substrate metrics
