@@ -146,11 +146,15 @@ class SimTrainer:
         return self._sim.place(params)
 
     # ------------------------------------------------------------- train
-    def _run_round(self, params, batch_fn, rnd, lr_fn, log_every, eval_fn,
-                   failure_plan):
-        """One round of :meth:`run`, its host work in ``dfl.*`` spans."""
+    def _run_round(self, params, batches, batch_fn, rnd, rounds, lr_fn,
+                   log_every, eval_fn, failure_plan):
+        """One round of :meth:`run`, its host work in ``dfl.*`` spans.
+        ``batches`` is round ``rnd``'s batch where the round before built it
+        ahead, else None; returns the params, round ``rnd + 1``'s batch
+        (None after the last round) and the round's record."""
         with span("dfl.batch"):
-            batches = batch_fn(rnd)
+            if batches is None:  # a call's first round: none built ahead
+                batches = batch_fn(rnd)
         with span("dfl.operands"):
             if failure_plan is not None:
                 mask = failure_plan.alive_mask(rnd)
@@ -172,6 +176,13 @@ class SimTrainer:
             params, losses, metrics, *carried = self._round_fn(
                 params, batches, lr_t, alive_t, gates, attack, akey, *extra)
             self._inflight, self._codec_state = self._sim.carried(carried)
+        ahead = None
+        if rnd + 1 < rounds:
+            # the device runs round rnd while the host builds the next batch;
+            # the span stays outside the dfl. leaf spans, so each round
+            # keeps the five that perf/scopes.py splits its time by
+            with span("sim.batch_ahead"):
+                ahead = batch_fn(rnd + 1)
         with span("dfl.sync"):
             loss = float(jnp.mean(losses))
         with span("dfl.record"):
@@ -186,7 +197,7 @@ class SimTrainer:
                                           if k != "round"})
             if self.ckpt is not None:
                 self.ckpt.maybe_save(rnd, params, {"round": rnd})
-        return params, rec
+        return params, ahead, rec
 
     def run(self, params: PyTree, batch_fn: Callable[[int], PyTree],
             rounds: int, lr_fn: Callable[[int], float],
@@ -194,13 +205,24 @@ class SimTrainer:
             eval_fn: Callable[[PyTree], dict] | None = None,
             failure_plan: failures_lib.FailurePlan | None = None
             ) -> tuple[PyTree, list[dict]]:
+        """Runs rounds [``start_round``, ``rounds``); returns the params and
+        one record per round.
+
+        The host keeps one batch ahead of the device: ``batch_fn(r + 1)``
+        is called once round r is dispatched, before the host waits for
+        its loss, and so before round r's record, eval and checkpoint. A
+        batch function must therefore depend on the round index alone.
+        ``batch_fn`` is called once per round, in order, and never for a
+        round past ``rounds``; an exception it raises leaves ``run`` at
+        once, with the round before it dispatched and not synced."""
         history: list[dict] = []
         params = self._sim.place(params)
+        batches = None
         for rnd in range(start_round, rounds):
             with span("dfl.round", step=rnd):
-                params, rec = self._run_round(params, batch_fn, rnd, lr_fn,
-                                              log_every, eval_fn,
-                                              failure_plan)
+                params, batches, rec = self._run_round(
+                    params, batches, batch_fn, rnd, rounds, lr_fn, log_every,
+                    eval_fn, failure_plan)
             history.append(rec)
         return params, history
 

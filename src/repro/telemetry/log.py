@@ -23,7 +23,8 @@ a profiler timeline when one is being captured.
 operations and close to free while no profiler runs. The trainers mark each
 round ``dfl.round`` (its step number is the round index) and its host work
 ``dfl.batch`` / ``dfl.operands`` / ``dfl.dispatch`` / ``dfl.sync`` /
-``dfl.record``.
+``dfl.record``; ``SimTrainer.run`` builds the next round's batch between
+``dfl.dispatch`` and ``dfl.sync`` under ``sim.batch_ahead``.
 
 ``round_every=k`` samples the round records: only every k-th round is
 emitted (``rnd % k == 0``), and both trainers consult ``wants_round``

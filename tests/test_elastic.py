@@ -561,3 +561,126 @@ def test_trainers_reject_a_shard_map_engine(cls):
         cls(overlay=expander_overlay(8, 4, seed=0), loss_fn=quad_loss,
             dcfg=dfedavg.DFedAvgMConfig(local_steps=1, lr=0.2, momentum=0.0),
             engine=engine.GossipEngineConfig(substrate="shard_map"))
+
+
+# ------------------------------------------- the host loop's batch ahead
+def _sim_trainer(cell="stacked_f32"):
+    return SimTrainer(overlay=expander_overlay(8, 4, seed=0),
+                      loss_fn=_two_leaf_loss,
+                      dcfg=dfedavg.DFedAvgMConfig(local_steps=2, lr=0.2,
+                                                  momentum=0.5),
+                      engine=engine.GossipEngineConfig(
+                          **{"substrate": "stacked", **PARITY_CELLS[cell]}))
+
+
+def _recording_batch_fn(fail_at=None):
+    """A batch function of the round index alone that records its calls."""
+    calls = []
+
+    def batch_fn(rnd):
+        calls.append(rnd)
+        if rnd == fail_at:
+            raise RuntimeError(f"no batch for round {rnd}")
+        r = np.random.default_rng((7, rnd))
+        return _batches(jnp.asarray(r.standard_normal((8, 32)),
+                                    jnp.float32), 2)
+
+    return batch_fn, calls
+
+
+def _p0():
+    r = np.random.default_rng(4)
+    return {"w": jnp.asarray(r.standard_normal((8, 32)), jnp.float32),
+            "b": jnp.asarray(r.standard_normal((8, 3)), jnp.float32)}
+
+
+def test_batch_fn_called_once_per_round_in_order():
+    """run(p, fn, 1) then run(p, fn, 4, start_round=1) asks for rounds
+    0-3, once each and in order, and never for round 4: a caller that
+    keeps the first three batches it is fed keeps rounds 0-2."""
+    sim = _sim_trainer()
+    batch_fn, calls = _recording_batch_fn()
+    kept = []
+
+    def feed(rnd):
+        b = batch_fn(rnd)
+        if len(kept) < 3:
+            kept.append(rnd)
+        return b
+
+    p1, h1 = sim.run(_p0(), feed, 1, lambda rnd: 0.2)
+    _, h4 = sim.run(p1, feed, 4, lambda rnd: 0.2, start_round=1)
+    assert calls == [0, 1, 2, 3]
+    assert kept == [0, 1, 2]
+    assert [h["round"] for h in h1 + h4] == [0, 1, 2, 3]
+
+
+@pytest.mark.parametrize("cell", ["stacked_f32", "int8_block_delay1"])
+def test_run_equals_chained_one_round_calls(cell):
+    """run(p, fn, 4), which builds each batch a round ahead, gives params
+    and history bitwise equal to four one-round calls, which never do; the
+    delayed int8 cell carries its in-flight snapshot between rounds."""
+    p0 = _p0()
+    batch_fn, _ = _recording_batch_fn()
+    p_run, h_run = _sim_trainer(cell).run(p0, batch_fn, 4, lambda rnd: 0.2)
+    sim, p_one, h_one = _sim_trainer(cell), p0, []
+    for rnd in range(4):
+        p_one, h = sim.run(p_one, batch_fn, rnd + 1, lambda r: 0.2,
+                           start_round=rnd)
+        h_one += h
+    for k in p0:
+        np.testing.assert_array_equal(np.asarray(p_run[k]),
+                                      np.asarray(p_one[k]))
+    assert h_run == h_one
+
+
+def _spy_on_spans(monkeypatch):
+    """Records each host span train.py opens, as its name or, for a step
+    span, (name, step), in a list it returns."""
+    from repro.launch import train
+    events = []
+    real = train.span
+
+    def spy(name, step=None):
+        events.append(name if step is None else (name, step))
+        return real(name, step)
+
+    monkeypatch.setattr(train, "span", spy)
+    return events
+
+
+def test_batch_fn_error_leaves_run_with_the_round_unsynced(monkeypatch):
+    """An exception raised by batch_fn(r + 1) propagates out of run at
+    once: round r was dispatched, its sync and record never ran."""
+    names = _spy_on_spans(monkeypatch)
+    batch_fn, calls = _recording_batch_fn(fail_at=2)
+    with pytest.raises(RuntimeError, match="no batch for round 2"):
+        _sim_trainer().run(_p0(), batch_fn, 5, lambda rnd: 0.2)
+    assert calls == [0, 1, 2]
+    last = len(names) - names[::-1].index(("dfl.round", 1))
+    assert names[last:] == ["dfl.batch", "dfl.operands", "dfl.dispatch",
+                            "sim.batch_ahead"]
+
+
+def test_run_builds_the_next_batch_between_dispatch_and_sync(monkeypatch):
+    """Within each round but the last the host spans run dfl.dispatch ->
+    sim.batch_ahead -> dfl.sync, and the batch built there is the next
+    round's; every round keeps its dfl.batch span, which builds the batch
+    only in a call's first round."""
+    events = _spy_on_spans(monkeypatch)
+    batch_fn, calls = _recording_batch_fn()
+
+    def feed(rnd):
+        events.append(("batch_fn", rnd))
+        return batch_fn(rnd)
+
+    _sim_trainer().run(_p0(), feed, 3, lambda rnd: 0.2)
+    leaves = ["dfl.operands", "dfl.dispatch"]
+    tail = ["dfl.sync", "dfl.record"]
+    assert events == [
+        ("dfl.round", 0), "dfl.batch", ("batch_fn", 0), *leaves,
+        "sim.batch_ahead", ("batch_fn", 1), *tail,
+        ("dfl.round", 1), "dfl.batch", *leaves,
+        "sim.batch_ahead", ("batch_fn", 2), *tail,
+        ("dfl.round", 2), "dfl.batch", *leaves, *tail]
+    assert calls == [0, 1, 2]
